@@ -1,7 +1,7 @@
 """Shared benchmark substrate: cached index builds + Deployment factories.
 
 Scale knobs via env: BENCH_N (points), BENCH_Q (queries), BENCH_P (servers).
-Indices are cached under artifacts/bench_cache keyed by their parameters;
+Graphs and indices are built once per process and kept in memory;
 the global graph + PQ are shared between BatANN and ScatterGather (the
 paper builds both over the same partitioning method [12]).  Index
 construction routes through the ``repro.api`` engines; the figure functions
@@ -24,42 +24,30 @@ BENCH_Q = int(os.environ.get("BENCH_Q", 256))
 BENCH_P = int(os.environ.get("BENCH_P", 8))
 DATASET = os.environ.get("BENCH_DATASET", "deep")
 R = int(os.environ.get("BENCH_R", 32))
-CACHE = os.path.join(os.path.dirname(__file__), "..", "artifacts",
-                     "bench_cache")
-
-
-def _cache_path(tag: str) -> str:
-    os.makedirs(CACHE, exist_ok=True)
-    return os.path.join(CACHE, f"{tag}_{DATASET}_{BENCH_N}.npz")
 
 
 def dataset() -> synth.Dataset:
     return synth.make_dataset(DATASET, n=BENCH_N, n_queries=BENCH_Q, seed=0)
 
 
+# built graphs, assignments and indices, kept for the life of the process
+_INDEX_CACHE: dict = {}
+
+
 def global_graph(ds) -> vamana.VamanaGraph:
-    path = _cache_path(f"graph_r{R}")
-    if os.path.exists(path):
-        z = np.load(path)
-        return vamana.VamanaGraph(neighbors=z["neighbors"],
-                                  medoid=int(z["medoid"]), R=R, L_build=0,
-                                  alpha=1.2)
-    knn = ref.brute_force_knn(ds.vectors, ds.vectors, 17)[:, 1:]
-    g = vamana.build_from_knn(ds.vectors, knn, r=R, alpha=1.2)
-    np.savez(path, neighbors=g.neighbors, medoid=g.medoid)
-    return g
+    if "graph" not in _INDEX_CACHE:
+        knn = ref.brute_force_knn(ds.vectors, ds.vectors, 17)[:, 1:]
+        _INDEX_CACHE["graph"] = vamana.build_from_knn(ds.vectors, knn, r=R,
+                                                      alpha=1.2)
+    return _INDEX_CACHE["graph"]
 
 
 def assignment(g, p: int) -> np.ndarray:
-    path = _cache_path(f"assign_p{p}")
-    if os.path.exists(path):
-        return np.load(path)["assign"]
-    a = part_mod.ldg_partition(g.neighbors, p, passes=3, seed=0)
-    np.savez(path, assign=a)
-    return a
-
-
-_INDEX_CACHE: dict = {}
+    key = ("assign", p)
+    if key not in _INDEX_CACHE:
+        _INDEX_CACHE[key] = part_mod.ldg_partition(g.neighbors, p, passes=3,
+                                                   seed=0)
+    return _INDEX_CACHE[key]
 
 
 def _bench_index_spec(engine: str, p: int) -> api.IndexSpec:

@@ -6,7 +6,9 @@ Prints ``name,us_per_call,derived`` CSV and writes both
 Scale via env: BENCH_N / BENCH_Q / BENCH_P (defaults 20000/256/8).
 
 ``--only <suite>[,<suite>]`` runs a subset (``--list`` names them) — the
-bench-smoke CI job and local iteration don't need the full sweep.
+bench-smoke CI job and local iteration don't need the full sweep.  A suite
+that raises still writes its ``<tag>_FAILED`` row, and the run then exits
+nonzero.
 """
 
 from __future__ import annotations
@@ -22,6 +24,7 @@ import traceback
 _ROOT = os.path.join(os.path.dirname(__file__), "..")
 sys.path.insert(0, _ROOT)                       # benchmarks package
 sys.path.insert(0, os.path.join(_ROOT, "src"))  # repro package
+ARTIFACTS = os.path.join(_ROOT, "artifacts")
 
 # suite tag -> "module.function" within the benchmarks package.  Module-
 # level (with lazy resolution in main) so `--list`, tools/check_docs.py's
@@ -84,6 +87,9 @@ def main() -> None:
                 f"error: unknown suite tag(s): {', '.join(unknown)} "
                 f"(valid: {', '.join(known)})")
         selected = [(tag, spec) for tag, spec in SUITES if tag in want]
+    from repro import compile_cache
+
+    compile_cache.enable()
     suites = [(tag, _resolve(spec)) for tag, spec in selected]
     all_rows = []
     print("name,us_per_call,derived")
@@ -100,7 +106,7 @@ def main() -> None:
             all_rows.append((name, us, derived))
         print(f"# {tag} done in {time.time()-t0:.0f}s", flush=True)
 
-    out = os.path.join(os.path.dirname(__file__), "..", "artifacts")
+    out = ARTIFACTS
     os.makedirs(out, exist_ok=True)
     with open(os.path.join(out, "bench.csv"), "w") as f:
         f.write("name,us_per_call,derived\n")
@@ -112,6 +118,9 @@ def main() -> None:
             f, indent=2, sort_keys=True,
         )
         f.write("\n")
+    failed = [n for n, _, _ in all_rows if n.endswith("_FAILED")]
+    if failed:
+        raise SystemExit(f"error: failed suite(s): {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -5,9 +5,9 @@ bit-exactly — plus a failover demonstration.
 Setup routes through the documented ``repro.api`` service layer
 (``Deployment.from_config`` builds the dataset + index; failover re-wraps
 the rescaled index with ``Deployment.from_parts``).  The SPMD execution
-itself still drives engine internals (device states, shard pytrees,
-``make_spmd_fn``) *below* the API — the one remaining entry point the
-``Engine`` protocol does not cover; see docs/ARCHITECTURE.md "Known gap".
+itself calls the engine's multi-device driver ``baton.run_spmd`` *below*
+the API — the one remaining entry point the ``Engine`` protocol does not
+cover; see docs/ARCHITECTURE.md "Known gap".
 Start from ``examples/quickstart.py`` for the pure Deployment-level API.
 
     PYTHONPATH=src python examples/distributed_search.py
@@ -18,15 +18,12 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import numpy as np
 import jax
-import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from repro.api import (
     DataSpec, Deployment, IndexSpec, SearchParams, ServeConfig,
 )
 from repro.api.engine import BatonEngine
 from repro.core import baton
-from repro.core.beam_search import Shard
 from repro.ft.elastic import rescale_assignment
 
 
@@ -52,32 +49,7 @@ def main():
 
     print("\n== SPMD: shard_map over 8 devices, all_to_all state routing ==")
     mesh = jax.make_mesh((8,), ("part",))
-    q_dev, qid_dev, st_dev, sd_dev, B, Bp, per = baton._split_round_robin(
-        index, ds.queries, cfg)
-    codebook = jnp.asarray(index.codebook)
-    devs = jax.vmap(
-        lambda q, i, s, sd: baton.init_device_state(q, i, s, sd, cfg,
-                                                    codebook))(
-        jnp.asarray(q_dev), jnp.asarray(qid_dev), jnp.asarray(st_dev),
-        jnp.asarray(sd_dev))
-    shard = index.stacked_shards()
-    fn = baton.make_spmd_fn(cfg, n_parts=8, axis_name="part")
-
-    def body(d, s, c):
-        d1 = jax.tree.map(lambda x: x[0], d)
-        s1 = Shard(s.vectors[0], s.neighbors[0], s.codes, s.node2part,
-                   s.node2local)
-        return jax.tree.map(lambda x: x[None], fn(d1, s1, c))
-
-    dev_specs = jax.tree.map(lambda _: P("part"), devs)
-    shard_specs = Shard(vectors=P("part"), neighbors=P("part"), codes=P(),
-                        node2part=P(), node2local=P())
-    from repro.compat import shard_map
-    out = jax.jit(shard_map(
-        body, mesh=mesh, in_specs=(dev_specs, shard_specs, P()),
-        out_specs=dev_specs, check=False,
-    ))(devs, shard, codebook)
-    ids_spmd, _, st2 = baton._collect(out, qid_dev, cfg, B, Bp, 8, per, 0)
+    ids_spmd, _, st2 = baton.run_spmd(index, ds.queries, cfg, mesh)
     match = np.array_equal(ids_sim, ids_spmd)
     from repro.core import ref
     print(f"recall@10={ref.recall_at_k(ids_spmd, ds.gt, 10):.3f} "

@@ -18,6 +18,7 @@ from __future__ import annotations
 import dataclasses
 import os
 import re
+import time
 
 import numpy as np
 
@@ -186,18 +187,26 @@ class Deployment:
     engine: object                      # repro.api.engine.Engine
     dataset: "synth.Dataset | None" = None
     cost: CostModel = COST
+    build_s: dict = dataclasses.field(default_factory=dict)  # phase -> s
 
     # --- constructors ------------------------------------------------------
     @classmethod
     def from_config(cls, config: ServeConfig,
                     index_cache: str | None = None,
                     dataset: "synth.Dataset | None" = None) -> "Deployment":
-        """Build (or load from ``index_cache``) the configured deployment."""
+        """Build (or load from ``index_cache``) the configured deployment.
+
+        ``build_s`` of the result holds the wall seconds of each phase this
+        call ran: ``data`` (dataset and its exact ground truth), then the
+        engine's own build phases."""
+        t0 = time.perf_counter()
         ds = dataset if dataset is not None else synth.make_dataset(
             config.data.name, n=config.data.n,
             n_queries=config.data.n_queries, seed=config.data.seed)
         dep = cls(config=config, engine=get_engine(config.index.engine),
                   dataset=ds)
+        if dataset is None:
+            dep.build_s["data"] = time.perf_counter() - t0
         cache_dir = (os.path.join(index_cache, config.index_key())
                      if index_cache else None)
         if cache_dir and ckpt.latest_step(cache_dir) is not None:
@@ -205,6 +214,7 @@ class Deployment:
             dep.engine.load_index(tree, meta)
             return dep
         dep.engine.build(ds, config.index)
+        dep.build_s.update(getattr(dep.index, "build_s", {}))
         if cache_dir:
             dep.save(cache_dir)
         return dep

@@ -66,14 +66,23 @@ def _vectors_of(dataset) -> np.ndarray:
                                 np.float32)
 
 
-def _build_graph(vectors: np.ndarray, spec) -> vamana.VamanaGraph:
-    """Global graph per ``IndexSpec.graph_mode`` (see configs.batann_serve)."""
+def _build_graph(vectors: np.ndarray, spec, build_s: dict) -> vamana.VamanaGraph:
+    """Global graph per ``IndexSpec.graph_mode`` (see configs.batann_serve);
+    records the seconds of each step in ``build_s``."""
     if spec.graph_mode == "knn":
+        t0 = time.perf_counter()
         knn = ref.brute_force_knn(vectors, vectors, spec.knn_k)[:, 1:]
-        return vamana.build_from_knn(vectors, knn, r=spec.r, alpha=spec.alpha)
+        build_s["knn"] = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        g = vamana.build_from_knn(vectors, knn, r=spec.r, alpha=spec.alpha)
+        build_s["graph"] = time.perf_counter() - t0
+        return g
     if spec.graph_mode == "vamana":
-        return vamana.build(vectors, r=spec.r, l_build=spec.l_build,
-                            alpha=spec.alpha, seed=spec.seed)
+        t0 = time.perf_counter()
+        g = vamana.build(vectors, r=spec.r, l_build=spec.l_build,
+                         alpha=spec.alpha, seed=spec.seed)
+        build_s["graph"] = time.perf_counter() - t0
+        return g
     raise ValueError(f"graph_mode must be knn|vamana: {spec.graph_mode}")
 
 
@@ -112,8 +121,9 @@ class BatonEngine:
     # --- build / attach ----------------------------------------------------
     def build(self, dataset, spec, graph=None, assign=None):
         vectors = _vectors_of(dataset)
+        build_s = {}
         if graph is None and spec.graph_mode == "knn":
-            graph = _build_graph(vectors, spec)
+            graph = _build_graph(vectors, spec, build_s)
         self.index = baton.build_index(
             vectors, p=spec.p, r=spec.r, l_build=spec.l_build,
             alpha=spec.alpha, pq_m=spec.pq_m, pq_k=spec.pq_k,
@@ -121,6 +131,7 @@ class BatonEngine:
             seed=spec.seed, graph=graph, codes_mode=spec.codes_mode,
             assign=assign,
         )
+        self.index.build_s = {**build_s, **self.index.build_s}
         return self.index
 
     def attach(self, index):
@@ -384,14 +395,7 @@ class ExactEngine:
     def search(self, queries, params) -> SearchResult:
         t0 = time.time()
         queries = np.asarray(queries, np.float32)
-        ids = ref.brute_force_knn(self.index.vectors, queries, params.k)
-        # distances chunked like brute_force_knn — never materialize the
-        # full (B, N) matrix
-        dists = np.empty(ids.shape, np.float32)
-        for s in range(0, queries.shape[0], 1024):
-            d = ref.pairwise_sq_l2(queries[s:s + 1024], self.index.vectors)
-            dists[s:s + 1024] = np.take_along_axis(d, ids[s:s + 1024],
-                                                   axis=1)
+        ids, dists = ref.exact_knn(self.index.vectors, queries, params.k)
         b = queries.shape[0]
         zeros = np.zeros(b, np.int64)
         stats = {

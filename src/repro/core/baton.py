@@ -47,18 +47,22 @@ Execution model (TPU adaptation of the paper's asynchronous TCP relay):
 
 The same per-device functions are driven two ways: ``run_simulated`` (vmap
 over the partition axis — single-host benchmarks; bit-identical math) and
-``make_spmd_fn`` (shard_map over a real mesh axis — multi-device tests and
-the 512-chip dry-run).
+``run_spmd`` (``make_spmd_fn`` under shard_map over a real mesh axis, one
+partition per device — multi-device runs and the 512-chip dry-run).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import math
+import time
+from functools import lru_cache, partial
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as PSpec
 
 from repro.core import beam_search, head_index, partition as part_mod, pq, vamana
 from repro.core.beam_search import (
@@ -89,9 +93,10 @@ class BatonParams:
     # --- hot-path implementation knobs (all default to the fused path) ----
     fused: bool = True       # slot-batched scoring + single-pass merges;
     #                          False = per-slot seed path (equivalence ref)
-    adc_impl: str = "gather"  # "gather" (CPU fallback) | "mxu" (dense
-    #                          Pallas one-hot, ulp-level diffs) | "mxu_tiled"
-    #                          (slot-tiled Pallas, bit-identical to gather)
+    adc_impl: str = "gather"  # "gather" (XLA gather; the default on every
+    #                          platform) | "mxu" (dense Pallas one-hot,
+    #                          ulp-level diffs) | "mxu_tiled" (slot-tiled
+    #                          Pallas, bit-identical to gather)
     merge_impl: str = "lexsort"  # "lexsort" | "bitonic" (Pallas top-k)
     ship_lut: bool = False   # §8: ship the LUT in the envelope (True) vs
     #                          rebuild on arrival (False — the paper's
@@ -151,6 +156,7 @@ class BatonIndex:
     assign: np.ndarray         # (N,) partition assignment
     graph: "vamana.VamanaGraph"
     part_nbr_codes: "np.ndarray | None" = None  # (P, Npmax, R, M) sector mode
+    build_s: dict = dataclasses.field(default_factory=dict)  # phase -> seconds
 
     def stacked_shards(self, sector_codes: bool = False) -> Shard:
         """Shard pytree: (P,)-leading per-partition leaves + replicated maps.
@@ -200,12 +206,19 @@ def build_index(
     codes_mode: str = "replicated",    # or "sector" (AiSAQ layout, §Perf)
     assign: "np.ndarray | None" = None,  # pre-computed partition assignment
 ) -> BatonIndex:
-    """Build the global graph, partition it, lay out per-partition sectors."""
+    """Build the global graph, partition it, lay out per-partition sectors.
+
+    The returned index's ``build_s`` holds the wall seconds of each phase
+    it ran (``graph``, ``partition``, ``pq``, ``head``)."""
     vectors = np.ascontiguousarray(vectors, np.float32)
     n, d = vectors.shape
+    build_s = {}
+    t0 = time.perf_counter()
     if graph is None:
         graph = vamana.build(vectors, r=r, l_build=l_build, alpha=alpha, seed=seed)
+        build_s["graph"] = time.perf_counter() - t0
 
+    t0 = time.perf_counter()
     if assign is not None:
         assign = np.asarray(assign, np.int32)
     elif partitioner == "ldg":
@@ -224,15 +237,20 @@ def build_index(
         ok = ids >= 0
         part_vectors[pi, ok] = vectors[ids[ok]]
         part_neighbors[pi, ok] = graph.neighbors[ids[ok]]
+    build_s["partition"] = time.perf_counter() - t0
 
+    t0 = time.perf_counter()
     cb = pq.train(vectors, m=pq_m, k=pq_k, seed=seed)
     codes = pq.encode(cb, vectors)
+    build_s["pq"] = time.perf_counter() - t0
+    t0 = time.perf_counter()
     head = head_index.build(vectors, fraction=head_fraction, seed=seed)
+    build_s["head"] = time.perf_counter() - t0
 
     part_nbr_codes = None
     if codes_mode == "sector":
         part_nbr_codes = np.zeros(
-            part_neighbors.shape + (pq_m,), np.uint8
+            part_neighbors.shape + codes.shape[1:], np.uint8
         )
         safe = np.clip(part_neighbors, 0, n - 1)
         part_nbr_codes[:] = codes[safe]
@@ -245,6 +263,7 @@ def build_index(
         head_vectors=head.vectors, head_neighbors=head.neighbors,
         head_sample_ids=head.sample_ids, head_medoid=head.medoid,
         assign=assign, graph=graph, part_nbr_codes=part_nbr_codes,
+        build_s=build_s,
     )
 
 
@@ -793,10 +812,18 @@ def run_simulated(index: BatonIndex, queries: np.ndarray, cfg: BatonParams,
     Bit-identical math to the SPMD path; the measurement substrate for every
     paper figure (counters are exact; time comes from io_sim's cost model).
     """
-    P = index.p
+    devs, codebook, split = _initial_devices(index, queries, cfg)
+    shard = index.stacked_shards(sector_codes=sector_codes)
+    devs, n_supersteps = run_supersteps(devs, shard, codebook, cfg=cfg,
+                                        P=index.p)
+    return _collect(devs, *split, n_supersteps)
+
+
+def _initial_devices(index: BatonIndex, queries: np.ndarray, cfg: BatonParams):
+    """Queries dealt round-robin to the P devices' queues: (stacked device
+    states, codebook, the arguments :func:`_collect` needs besides them)."""
     q_dev, qid_dev, st_dev, sd_dev, B, Bp, per = _split_round_robin(
         index, queries, cfg)
-    shard = index.stacked_shards(sector_codes=sector_codes)
     codebook = jnp.asarray(index.codebook)
     devs = jax.vmap(
         lambda q, i, s, sd: init_device_state(q, i, s, sd, cfg, codebook)
@@ -804,10 +831,22 @@ def run_simulated(index: BatonIndex, queries: np.ndarray, cfg: BatonParams,
         jnp.asarray(q_dev), jnp.asarray(qid_dev), jnp.asarray(st_dev),
         jnp.asarray(sd_dev)
     )
+    return devs, codebook, (qid_dev, cfg, B, Bp, index.p, per)
+
+
+@partial(jax.jit, static_argnames=("cfg", "P"))
+def run_supersteps(devs: DeviceState, shard: Shard, codebook, cfg: BatonParams,
+                   P: int):
+    """Super-steps of all P vmapped devices until every query is delivered.
+
+    The index arrives as arguments (``shard``, ``codebook``), never as
+    constants baked into the program, so one compilation per (cfg, P,
+    shapes) serves every call.  Returns (final devices, super-step count).
+    """
     my_parts = jnp.arange(P, dtype=jnp.int32)
     shard_axes = Shard(vectors=0, neighbors=0, codes=None, node2part=None,
                        node2local=None,
-                       nbr_codes=0 if sector_codes else None)
+                       nbr_codes=None if shard.nbr_codes is None else 0)
 
     def superstep(devs):
         devs, res_buf, dest, want, free, remaining = jax.vmap(
@@ -847,10 +886,9 @@ def run_simulated(index: BatonIndex, queries: np.ndarray, cfg: BatonParams,
         devs, rem = superstep(devs)
         return devs, it + 1, rem
 
-    devs, n_supersteps, _ = jax.jit(
-        lambda d: jax.lax.while_loop(cond, body, (d, jnp.int32(0), jnp.int32(1)))
-    )(devs)
-    return _collect(devs, qid_dev, cfg, B, Bp, P, per, n_supersteps)
+    devs, n_supersteps, _ = jax.lax.while_loop(
+        cond, body, (devs, jnp.int32(0), jnp.int32(1)))
+    return devs, n_supersteps
 
 
 def make_spmd_fn(cfg: BatonParams, n_parts: int, axis_name: str = "part"):
@@ -897,3 +935,69 @@ def make_spmd_fn(cfg: BatonParams, n_parts: int, axis_name: str = "part"):
         return dev
 
     return fn
+
+
+def _axis_size(mesh, axis_name) -> int:
+    names = axis_name if isinstance(axis_name, tuple) else (axis_name,)
+    return math.prod(mesh.shape[a] for a in names)
+
+
+def _spmd_shardings(mesh, axis_name, sector_codes: bool):
+    """(device states, shard, codebook) shardings: per-partition leaves split
+    along ``axis_name``, global maps and the codebook replicated."""
+    part, rep = NamedSharding(mesh, PSpec(axis_name)), NamedSharding(mesh, PSpec())
+    shard = Shard(vectors=part, neighbors=part, codes=rep, node2part=rep,
+                  node2local=rep, nbr_codes=part if sector_codes else None)
+    return part, shard, rep
+
+
+@lru_cache(maxsize=8)
+def spmd_program(cfg: BatonParams, mesh, axis_name="part",
+                 sector_codes: bool = False):
+    """The search as one SPMD program: :func:`make_spmd_fn` under
+    ``shard_map``, partition p on the p-th device along ``axis_name``.
+
+    ``(devs, shard, codebook) -> devs``, with the stacked device states and
+    the per-partition shard leaves split along their leading axis and the
+    rest replicated (the layout of :func:`run_supersteps`' arguments).
+    Cached per (cfg, mesh, axes), so repeated calls reuse one compilation.
+    """
+    fn = make_spmd_fn(cfg, n_parts=_axis_size(mesh, axis_name),
+                      axis_name=axis_name)
+
+    def body(dv, s, cb):
+        s1 = s._replace(
+            vectors=s.vectors[0], neighbors=s.neighbors[0],
+            nbr_codes=None if s.nbr_codes is None else s.nbr_codes[0])
+        out = fn(jax.tree.map(lambda x: x[0], dv), s1, cb)
+        return jax.tree.map(lambda x: x[None], out)
+
+    shardings = _spmd_shardings(mesh, axis_name, sector_codes)
+    specs = jax.tree.map(lambda s: s.spec, shardings,
+                         is_leaf=lambda x: isinstance(x, NamedSharding))
+    return jax.jit(
+        jax.shard_map(body, mesh=mesh, in_specs=specs, out_specs=specs[0],
+                      check_vma=False),
+        in_shardings=shardings, donate_argnums=(0,))
+
+
+def run_spmd(index: BatonIndex, queries: np.ndarray, cfg: BatonParams, mesh):
+    """Multi-device driver: one partition per device of ``mesh`` (all its
+    axes, flattened), states routed with ``all_to_all``.
+
+    Returns :func:`run_simulated`'s (ids, dists, stats), bit-identical to
+    it.  ``stats["part_device"]`` lists, for each partition, the id of the
+    device that holds its vectors, read from the placed array.
+    """
+    if mesh.size != index.p:
+        raise ValueError(f"{index.p} partitions on {mesh.size} devices")
+    axes = tuple(mesh.axis_names)
+    devs, codebook, split = _initial_devices(index, queries, cfg)
+    args = jax.device_put((devs, index.stacked_shards(), codebook),
+                          _spmd_shardings(mesh, axes, False))
+    placed = {s.index[0].start or 0: s.device.id
+              for s in args[1].vectors.addressable_shards}
+    out = spmd_program(cfg, mesh, axes)(*args)
+    ids, dists, stats = _collect(out, *split, 0)
+    stats["part_device"] = [placed.get(p) for p in range(index.p)]
+    return ids, dists, stats

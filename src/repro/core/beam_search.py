@@ -333,7 +333,7 @@ def step_disk(
 
     vecs, nbrs, ncodes = read_sectors(shard, gids)               # (W,d),(W,R)
     # exact distances of the expanded nodes -> rerank pool
-    ed = jnp.sum((vecs - state.query[None, :]) ** 2, -1)
+    ed = pq.ordered_sum((vecs - state.query[None, :]) ** 2)
     ed = jnp.where(gids == NO_ID, INF, ed)
     if fused:
         pool_ids, pool_dists = merge_pool_fused(
@@ -411,8 +411,8 @@ def step_disk_batched(
     """Slot-batched ``step_disk``: one super-step of work for all S resident
     states in single fused ops.
 
-    Candidate PQ scoring is one (S, W·R) call — ``pq.adc_slots`` (gather, the
-    CPU fallback, bit-identical to the per-slot path), the dense Pallas MXU
+    Candidate PQ scoring is one (S, W·R) call — ``pq.adc_slots`` (XLA
+    gather, the default, bit-identical to the per-slot path), the dense Pallas MXU
     one-hot kernel (``adc_impl="mxu"``, ulp-level differences), or the
     slot-tiled Pallas grid (``adc_impl="mxu_tiled"``, bit-identical to the
     gather without the dense route's S× FLOP overcommit) — instead of S
@@ -429,7 +429,7 @@ def step_disk_batched(
     R = nbrs.shape[-1]
     nbrs = nbrs.reshape(S, W, R)
 
-    ed = jnp.sum((vecs - states.query[:, None, :]) ** 2, -1)     # (S, W)
+    ed = pq.ordered_sum((vecs - states.query[:, None, :]) ** 2)  # (S, W)
     ed = jnp.where(gids == NO_ID, INF, ed)
     pool_ids, pool_dists = merge_pool_fused(
         states.pool_ids, states.pool_dists, gids, ed, impl=merge_impl

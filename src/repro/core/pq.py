@@ -5,6 +5,18 @@ node and performs almost all distance comparisons with it (§2, §5 "Memory
 footprint").  This module is the pure-JAX substrate; the MXU-optimized ADC
 lives in ``repro.kernels.pq_adc`` and is validated against this code.
 
+Codes are residual (IVFADC, Jégou et al. 2011 §4): a coarse k-means centroid
+c is subtracted from each vector x and the residual r = x - c is product
+quantized, so the M subspace quantizers spend their bits on the spread
+around c rather than on where c lies.  A vector's code has M + 2 bytes: the
+M residual codes, the coarse centroid's id, and the id of the level nearest
+to the vector's cross term 2<c, r̂>.  With them
+
+    ||q - c - r̂||² = ||q - c||² + Σ_m (||r̂_m||² - 2<q_m, r̂_m>) + 2<c, r̂>,
+
+one table entry per code byte, so ADC stays a sum of M + 2 lookups and every
+consumer of a (M + 2, K) table works unchanged.
+
 Conventions: squared-L2 everywhere (paper §2).  codes are uint8 with K<=256.
 """
 
@@ -18,28 +30,72 @@ import jax.numpy as jnp
 import numpy as np
 
 
+def ordered_sum(x: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
+    """Sum over ``axis`` in one fixed pairwise order, as elementwise adds.
+
+    A ``jnp.sum`` leaves the reduction order to the compiler, and on a TPU
+    the order follows the program's layout: the same distances summed in a
+    vmapped program and in a ``shard_map`` or per-worker program differ in
+    their last bits.  Elementwise adds round the same in every program, so
+    every search distance (exact and ADC) is reduced here.
+    """
+    x = jnp.moveaxis(x, axis, -1)
+    while x.shape[-1] > 1:
+        h = x.shape[-1] // 2
+        s = x[..., :h] + x[..., h:2 * h]
+        x = jnp.concatenate([s, x[..., 2 * h:]], -1) if x.shape[-1] % 2 else s
+    return x[..., 0]
+
+
 @dataclasses.dataclass
 class PQCodebook:
-    centroids: jnp.ndarray  # (M, K, dsub) float32
+    """One (M + 2, K, d) float32 array, so that it travels through jit
+    arguments, shardings and index files as a single leaf:
+
+    * rows ``[0, M)``: subspace m's residual centroids, in columns
+      ``[0, dsub)`` (the rest is zero);
+    * row ``M``: the coarse centroids (K, d);
+    * row ``M + 1``: the cross-term levels, in column 0.
+
+    ``centroids.shape[:2]`` is the shape of a query's table (see
+    :func:`build_lut`) and ``centroids.shape[0]`` the bytes of a code."""
+
+    centroids: jnp.ndarray  # (M + 2, K, d) float32
 
     @property
     def m(self) -> int:
-        return self.centroids.shape[0]
+        return self.centroids.shape[0] - 2
 
     @property
     def k(self) -> int:
         return self.centroids.shape[1]
 
     @property
-    def dsub(self) -> int:
+    def dim(self) -> int:
         return self.centroids.shape[2]
 
     @property
-    def dim(self) -> int:
-        return self.m * self.dsub
+    def dsub(self) -> int:
+        return self.dim // self.m
 
     def tree_flatten(self):
         return (self.centroids,), None
+
+
+def code_width(m: int) -> int:
+    """Bytes of one code (and rows of one table) for M residual subspaces."""
+    return m + 2
+
+
+def codebook_shape(m: int, k: int, dim: int) -> tuple[int, int, int]:
+    """Shape of the codebook array for M subspaces of K centroids in dim."""
+    return (code_width(m), k, dim)
+
+
+def _parts(cent: jnp.ndarray):
+    """(residual (M, K, dsub), coarse (K, d), levels (K,)) of a codebook."""
+    m = cent.shape[0] - 2
+    return cent[:m, :, :cent.shape[2] // m], cent[m], cent[m + 1, :, 0]
 
 
 def _split(x: jnp.ndarray, m: int) -> jnp.ndarray:
@@ -47,6 +103,22 @@ def _split(x: jnp.ndarray, m: int) -> jnp.ndarray:
     n, d = x.shape
     assert d % m == 0, f"dim {d} not divisible by M={m}"
     return x.reshape(n, m, d // m)
+
+
+def _sub_dists(xs: jnp.ndarray, cent: jnp.ndarray) -> jnp.ndarray:
+    """(N, M, dsub) x (M, K, dsub) -> (N, M, K) squared L2 per subspace.
+
+    Summed differences over the dsub coordinates, not the x² - 2x·c + c²
+    expansion: no matmul, so no backend runs it at reduced precision, and no
+    cancellation.  On a TPU v5e the matmul form of a 131072-row encode
+    agreed with a float64 encode on 98.7% of codes at DEFAULT precision and
+    on only 25.3% at HIGHEST; this form agreed on all of them
+    (``chip_smoke.py`` prints the three shares).
+    """
+    out = 0.0
+    for j in range(xs.shape[-1]):
+        out = out + (xs[:, :, None, j] - cent[None, :, :, j]) ** 2
+    return out
 
 
 @partial(jax.jit, static_argnums=(1, 2, 3))
@@ -63,17 +135,16 @@ def _kmeans_all_subspaces(x, m, k, iters):
     cent = xs[idx]                          # (K, M, dsub)
     cent = jnp.transpose(cent, (1, 0, 2))   # (M, K, dsub)
 
+    def per_subspace_sum(vals, assign):     # (N, M, c), (N, M) -> (M, K, c)
+        return jax.vmap(
+            lambda v, a: jax.ops.segment_sum(v, a, num_segments=k),
+            in_axes=(1, 1))(vals, assign)
+
     def step(cent, _):
-        # dists: (N, M, K)
-        d = (
-            jnp.sum(xs * xs, -1)[:, :, None]
-            - 2.0 * jnp.einsum("nmd,mkd->nmk", xs, cent)
-            + jnp.sum(cent * cent, -1)[None]
-        )
-        assign = jnp.argmin(d, axis=-1)     # (N, M)
-        onehot = jax.nn.one_hot(assign, k, dtype=x.dtype, axis=-1)  # (N, M, K)
-        sums = jnp.einsum("nmk,nmd->mkd", onehot, xs)
-        cnts = jnp.sum(onehot, axis=0)[..., None]    # (M, K, 1)
+        assign = jnp.argmin(_sub_dists(xs, cent), axis=-1)      # (N, M)
+        sums = per_subspace_sum(xs, assign)                     # (M, K, dsub)
+        cnts = per_subspace_sum(jnp.ones(xs.shape[:2] + (1,), x.dtype),
+                                assign)                         # (M, K, 1)
         new = jnp.where(cnts > 0, sums / jnp.maximum(cnts, 1), cent)
         return new, None
 
@@ -81,50 +152,91 @@ def _kmeans_all_subspaces(x, m, k, iters):
     return cent
 
 
+def _nearest(x: jnp.ndarray, cent: jnp.ndarray) -> jnp.ndarray:
+    """(N, d) x (K, d) -> (N,) id of the nearest row of ``cent``."""
+    return jnp.argmin(_sub_dists(x[:, None, :], cent[None])[:, 0], axis=-1)
+
+
+def _cross_term(coarse_rows: jnp.ndarray, res: jnp.ndarray,
+                rcodes: jnp.ndarray) -> jnp.ndarray:
+    """2<c, r̂> per row: (N, d) coarse centroids, (M, K, dsub) residual
+    centroids, (N, M) residual codes -> (N,)."""
+    m = res.shape[0]
+    r_hat = res[jnp.arange(m)[None], rcodes]                  # (N, M, dsub)
+    return 2.0 * ordered_sum(coarse_rows * r_hat.reshape(coarse_rows.shape))
+
+
 def train(
     x: np.ndarray, m: int = 32, k: int = 256, iters: int = 8, sample: int = 65536,
     seed: int = 0,
 ) -> PQCodebook:
+    """Coarse centroids, then the residual subspace centroids, then the
+    cross-term levels, each by k-means with K centroids on one sample."""
     x = np.asarray(x, dtype=np.float32)
     if x.shape[0] > sample:
         rng = np.random.default_rng(seed)
         x = x[rng.choice(x.shape[0], sample, replace=False)]
-    cent = _kmeans_all_subspaces(jnp.asarray(x), m, k, iters)
-    return PQCodebook(centroids=cent)
+    return PQCodebook(centroids=_train(jnp.asarray(x), m, k, iters))
 
 
-@partial(jax.jit, static_argnums=())
-def _encode(xs, cent):
-    d = (
-        jnp.sum(xs * xs, -1)[:, :, None]
-        - 2.0 * jnp.einsum("nmd,mkd->nmk", xs, cent)
-        + jnp.sum(cent * cent, -1)[None]
-    )
-    return jnp.argmin(d, axis=-1).astype(jnp.uint8)
+@partial(jax.jit, static_argnums=(1, 2, 3))
+def _train(x, m, k, iters):
+    d = x.shape[1]
+    coarse = _kmeans_all_subspaces(x, 1, k, iters)[0]         # (K, d)
+    cid = _nearest(x, coarse)
+    res = _kmeans_all_subspaces(x - coarse[cid], m, k, iters)  # (M, K, dsub)
+    rcodes = jnp.argmin(_sub_dists(_split(x - coarse[cid], m), res), -1)
+    cross = _cross_term(coarse[cid], res, rcodes)
+    levels = _kmeans_all_subspaces(cross[:, None], 1, k, iters)[0, :, 0]
+    cent = jnp.zeros(codebook_shape(m, k, d), jnp.float32)
+    cent = cent.at[:m, :, :d // m].set(res)
+    cent = cent.at[m].set(coarse)
+    return cent.at[m + 1, :, 0].set(levels)
+
+
+@jax.jit
+def _encode(x, cent):
+    res, coarse, levels = _parts(cent)
+    m = res.shape[0]
+    cid = _nearest(x, coarse)
+    rcodes = jnp.argmin(_sub_dists(_split(x - coarse[cid], m), res), -1)
+    cross = _cross_term(coarse[cid], res, rcodes)
+    lid = jnp.argmin(jnp.abs(cross[:, None] - levels[None]), axis=-1)
+    return jnp.concatenate(
+        [rcodes, cid[:, None], lid[:, None]], axis=1).astype(jnp.uint8)
 
 
 def encode(cb: PQCodebook, x: np.ndarray, chunk: int = 131072) -> np.ndarray:
-    """(N, d) -> (N, M) uint8 codes, chunked to bound memory."""
+    """(N, d) -> (N, M + 2) uint8 codes, chunked to bound memory."""
     x = np.asarray(x, dtype=np.float32)
-    out = np.empty((x.shape[0], cb.m), dtype=np.uint8)
+    cent = jnp.asarray(cb.centroids)
+    out = np.empty((x.shape[0], cent.shape[0]), dtype=np.uint8)
     for s in range(0, x.shape[0], chunk):
-        xs = _split(jnp.asarray(x[s : s + chunk]), cb.m)
-        out[s : s + chunk] = np.asarray(_encode(xs, cb.centroids))
+        out[s : s + chunk] = np.asarray(_encode(jnp.asarray(x[s : s + chunk]),
+                                                cent))
     return out
 
 
 def build_lut(cb_centroids: jnp.ndarray, queries: jnp.ndarray) -> jnp.ndarray:
-    """Query-to-centroid lookup tables (the 'codebook' of §2).
+    """Query lookup tables (the 'codebook' of §2).
 
-    cb_centroids: (M, K, dsub); queries: (Q, d) -> (Q, M, K) float32 where
-    lut[q, m, c] = ||query_sub[q, m] - centroid[m, c]||^2.
+    cb_centroids: (M + 2, K, d) (see :class:`PQCodebook`); queries: (Q, d)
+    -> (Q, M + 2, K) float32 where, for code bytes (k_0..k_{M-1}, j, l),
+    ``sum_m lut[q, m, k_m] + lut[q, M, j] + lut[q, M + 1, l]`` is the
+    squared distance from the query to the coded vector:
+    ``lut[q, m, k] = ||r̂_mk||² - 2<q_m, r̂_mk>``, ``lut[q, M, j] =
+    ||q - c_j||²`` and ``lut[q, M + 1, l]`` the l-th cross-term level.
+    Every entry is a sum of elementwise products (no matmul).
     """
-    q = queries.reshape(queries.shape[0], cb_centroids.shape[0], -1)  # (Q,M,dsub)
-    return (
-        jnp.sum(q * q, -1)[:, :, None]
-        - 2.0 * jnp.einsum("qmd,mkd->qmk", q, cb_centroids)
-        + jnp.sum(cb_centroids * cb_centroids, -1)[None]
-    )
+    res, coarse, levels = _parts(cb_centroids)
+    qs = _split(queries, res.shape[0])                        # (Q, M, dsub)
+    rows = 0.0
+    for j in range(res.shape[-1]):
+        r = res[None, :, :, j]
+        rows = rows + r * (r - 2.0 * qs[:, :, None, j])       # (Q, M, K)
+    near = _sub_dists(queries[:, None, :], coarse[None])      # (Q, 1, K)
+    lvl = jnp.broadcast_to(levels, (queries.shape[0], 1, levels.shape[0]))
+    return jnp.concatenate([rows, near, lvl], axis=1)
 
 
 def quantize_lut_i8(lut: jnp.ndarray):
@@ -159,7 +271,7 @@ def adc(lut: jnp.ndarray, codes: jnp.ndarray) -> jnp.ndarray:
     g = jnp.take_along_axis(
         lut, c.T[None, :, :], axis=2
     )  # (Q, M, N)
-    return jnp.sum(g, axis=1)
+    return ordered_sum(g, axis=1)
 
 
 def adc_slots(luts: jnp.ndarray, codes: jnp.ndarray) -> jnp.ndarray:
@@ -173,13 +285,22 @@ def adc_slots(luts: jnp.ndarray, codes: jnp.ndarray) -> jnp.ndarray:
     """
     c = codes.astype(jnp.int32)                       # (S, C, M)
     g = jnp.take_along_axis(luts, c.transpose(0, 2, 1), axis=2)  # (S, M, C)
-    return jnp.sum(g, axis=1)
+    return ordered_sum(g, axis=1)
 
 
 def reconstruct(cb: PQCodebook, codes: jnp.ndarray) -> jnp.ndarray:
-    """Decode PQ codes back to vectors (for diagnostics)."""
+    """Decode codes back to vectors c + r̂ (for diagnostics)."""
+    res, coarse, _ = _parts(jnp.asarray(cb.centroids))
     c = codes.astype(jnp.int32)
-    gathered = jax.vmap(lambda cent, code: cent[code], in_axes=(0, 1))(
-        cb.centroids, c
-    )  # (M, N, dsub)
-    return jnp.transpose(gathered, (1, 0, 2)).reshape(codes.shape[0], -1)
+    m = res.shape[0]
+    r_hat = res[jnp.arange(m)[None], c[:, :m]]               # (N, M, dsub)
+    return coarse[c[:, m]] + r_hat.reshape(codes.shape[0], -1)
+
+
+def level_error(cb: PQCodebook, codes: jnp.ndarray) -> jnp.ndarray:
+    """(N,) level minus exact cross term of each code: ADC of a query and a
+    code is ``||q - reconstruct(code)||² + level_error(code)``."""
+    res, coarse, levels = _parts(jnp.asarray(cb.centroids))
+    c = codes.astype(jnp.int32)
+    m = res.shape[0]
+    return levels[c[:, m + 1]] - _cross_term(coarse[c[:, m]], res, c[:, :m])

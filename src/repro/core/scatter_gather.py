@@ -89,11 +89,11 @@ def build_index(
     npmax = local2global.shape[1]
     part_vectors = np.zeros((p, npmax, d), np.float32)
     part_neighbors = np.full((p, npmax, r), NO_ID, np.int32)
-    part_codes = np.zeros((p, npmax, pq_m), np.uint8)
     part_medoid = np.zeros((p,), np.int32)
 
     cb = pq.train(vectors, m=pq_m, k=pq_k, seed=seed)
     codes = pq.encode(cb, vectors)
+    part_codes = np.zeros((p, npmax, codes.shape[1]), np.uint8)
 
     for pi in range(p):
         ids = local2global[pi]
@@ -114,21 +114,14 @@ def build_index(
     )
 
 
-def run_simulated(
-    index: ScatterGatherIndex, queries: np.ndarray, L: int = 64, W: int = 8,
-    k: int = 10, pool: int = 256, max_hops: int = 512,
-):
-    """Scatter every query to all P local indices; merge exact top-k.
-
-    Returns (ids (B,k), dists (B,k), stats) where counters are summed over
-    partitions (the paper's accounting for this baseline, §6.3).
-    """
-    P = index.p
-    queries = np.asarray(queries, np.float32)
-    B = queries.shape[0]
-    jq = jnp.asarray(queries)
-    codebook = jnp.asarray(index.codebook)
-    npmax = index.part_vectors.shape[1]
+@partial(jax.jit, static_argnames=("L", "W", "k", "pool", "max_hops"))
+def _search_partitions(part_vectors, part_neighbors, part_codes, part_medoid,
+                       queries, codebook, L: int, W: int, k: int, pool: int,
+                       max_hops: int):
+    """Every query searched on every partition: (P, B, k) local ids, dists
+    and (P, B, 4) counters.  The index arrives as arguments, so one
+    compilation serves every call with the same shapes."""
+    npmax = part_vectors.shape[1]
 
     def search_partition(vec, nbr, codes, medoid, q):
         shard = Shard(
@@ -147,16 +140,31 @@ def run_simulated(
                        out.counters.dist_comps, out.counters.reads]),
         )
 
-    fn = jax.jit(
-        jax.vmap(                       # over partitions
-            jax.vmap(search_partition, in_axes=(None, None, None, None, 0)),
-            in_axes=(0, 0, 0, 0, None),
-        )
-    )
-    ids_l, dists, stats = fn(
+    return jax.vmap(                     # over partitions
+        jax.vmap(search_partition, in_axes=(None, None, None, None, 0)),
+        in_axes=(0, 0, 0, 0, None),
+    )(part_vectors, part_neighbors, part_codes, part_medoid, queries)
+
+
+def run_simulated(
+    index: ScatterGatherIndex, queries: np.ndarray, L: int = 64, W: int = 8,
+    k: int = 10, pool: int = 256, max_hops: int = 512,
+):
+    """Scatter every query to all P local indices; merge exact top-k.
+
+    Returns (ids (B,k), dists (B,k), stats) where counters are summed over
+    partitions (the paper's accounting for this baseline, §6.3).
+    """
+    P = index.p
+    queries = np.asarray(queries, np.float32)
+    B = queries.shape[0]
+    ids_l, dists, stats = _search_partitions(
         jnp.asarray(index.part_vectors), jnp.asarray(index.part_neighbors),
-        jnp.asarray(index.part_codes), jnp.asarray(index.part_medoid), jq,
+        jnp.asarray(index.part_codes), jnp.asarray(index.part_medoid),
+        jnp.asarray(queries), jnp.asarray(index.codebook),
+        L=L, W=W, k=k, pool=pool, max_hops=max_hops,
     )                                    # (P, B, k), (P, B, k), (P, B, 4)
+    npmax = index.part_vectors.shape[1]
 
     # local ids -> global ids
     l2g = jnp.asarray(index.local2global)  # (P, Npmax)

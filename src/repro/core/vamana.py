@@ -229,20 +229,51 @@ def _add_reverse_edges(vectors, jvec, neighbors, src_ids, pruned, r, alpha):
             overflow_q.append(qi)
             overflow_cands.append(cand)
     if overflow_q:
-        C = max(len(c) for c in overflow_cands)
-        B = len(overflow_q)
-        cids = np.full((B, C), NO_ID, dtype=np.int32)
+        widths = np.array([len(c) for c in overflow_cands])
+        cids = np.full((len(overflow_q), widths.max()), NO_ID, dtype=np.int32)
         for i, c in enumerate(overflow_cands):
             cids[i, : len(c)] = c
-        qv = vectors[np.asarray(overflow_q)]
-        cd = _exact_dists(vectors, qv, cids)
-        pr = np.asarray(
-            _robust_prune_batch(
-                jnp.asarray(qv), jnp.asarray(cids), jnp.asarray(cd), jvec,
-                r=r, alpha=alpha,
+        _prune_rows(vectors, jvec, neighbors, np.asarray(overflow_q), cids,
+                    widths, r, alpha)
+
+
+# Candidates (rows x candidate columns) pruned per device call.  The
+# (rows, candidates, d) f32 gather of ``_robust_prune_batch`` must stay far
+# below device memory even when every row of a 1M-point graph needs pruning
+# and a few hub rows carry thousands of candidates.
+PRUNE_ELEMS = 1 << 18
+
+
+def _prune_rows(vectors, jvec, neighbors, rows, cids, widths, r, alpha):
+    """``neighbors[rows] = RobustPrune(rows, cids)``, in bounded calls.
+
+    Row i's candidates are ``cids[i, :widths[i]]`` (the rest is ``NO_ID``).
+    Rows are grouped by their width rounded up to a power of two, so a hub
+    row does not widen every call, and each group is cut into calls of at
+    most ``PRUNE_ELEMS`` candidates; a group of more than one call pads its
+    last call with empty rows, so its calls share one shape.  Rows are
+    independent and ``NO_ID`` columns are never picked, so neither the
+    grouping nor the padding changes any row's result."""
+    bins = np.maximum(16, 1 << np.ceil(np.log2(np.maximum(widths, 1))).astype(int))
+    for c in np.unique(bins):
+        sel = np.flatnonzero(bins == c)
+        bs = min(max(8, PRUNE_ELEMS // c), len(sel))
+        for s in range(0, len(sel), bs):
+            part = sel[s:s + bs]
+            ids = rows[part]
+            cc = np.full((bs, c), NO_ID, np.int32)
+            w = min(c, cids.shape[1])
+            cc[: len(part), :w] = cids[part, :w]
+            qv = np.zeros((bs, vectors.shape[1]), np.float32)
+            qv[: len(part)] = vectors[ids]
+            cd = _exact_dists(vectors, qv, cc)
+            pr = np.asarray(
+                _robust_prune_batch(
+                    jnp.asarray(qv), jnp.asarray(cc), jnp.asarray(cd), jvec,
+                    r=r, alpha=alpha,
+                )
             )
-        )
-        neighbors[np.asarray(overflow_q)] = pr
+            neighbors[ids] = pr[: len(part)]
 
 
 def build_from_knn(
@@ -264,16 +295,8 @@ def build_from_knn(
     cand = np.concatenate([knn_ids.astype(np.int32), longe], axis=1)
     jvec = jnp.asarray(np.ascontiguousarray(vectors, np.float32))
     out = np.full((n, r), NO_ID, np.int32)
-    bs = 4096
-    for s in range(0, n, bs):
-        ids = np.arange(s, min(s + bs, n))
-        cd = _exact_dists(vectors, vectors[ids], cand[ids])
-        out[ids] = np.asarray(
-            _robust_prune_batch(
-                jnp.asarray(vectors[ids]), jnp.asarray(cand[ids]),
-                jnp.asarray(cd), jvec, r=r, alpha=alpha,
-            )
-        )
+    _prune_rows(vectors, jvec, out, np.arange(n), cand,
+                np.full(n, cand.shape[1]), r, alpha)
     medoid = int(np.argmin(((vectors - vectors.mean(0)) ** 2).sum(-1)))
     g = VamanaGraph(neighbors=out, medoid=medoid, R=r, L_build=0, alpha=alpha)
     # ensure medoid reaches out (it always has out-edges by construction) and

@@ -7,6 +7,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.core.pq import ordered_sum
 from repro.kernels.pq_adc.kernel import (
     DEFAULT_TC, DEFAULT_TN, DEFAULT_TQ, pq_adc_pallas, pq_adc_slots_pallas,
 )
@@ -76,11 +77,12 @@ def pq_adc_slots_tiled(
 ) -> jnp.ndarray:
     """(S, M, K) x (S, C, M) -> (S, C): slot-tiled, no cross-slot FLOPs.
 
-    ``adc_impl="mxu_tiled"``: the grid walks (slot, candidate tile,
-    subspace), so the MXU scores only each slot's own candidate block —
-    2·S·C·K·M FLOPs against the dense route's 2·S·(S·C)·K·M.  The kernel
+    ``adc_impl="mxu_tiled"``: the grid walks (slot, candidate tile) and
+    the kernel the subspaces, so the MXU scores only each slot's own
+    candidate block — 2·S·C·K·M FLOPs against the dense route's
+    2·S·(S·C)·K·M.  The kernel
     emits per-subspace partials (exact, see kernel.py) and this wrapper
-    reduces them with the gather's own ``jnp.sum`` — bit-identical to
+    reduces them with the gather's own ``pq.ordered_sum`` — bit-identical to
     ``repro.core.pq.adc_slots`` (tested), which is what lets the exec tier
     run it under the engine's bit-parity guarantee.
     """
@@ -90,7 +92,7 @@ def pq_adc_slots_tiled(
     tc = tc or min(DEFAULT_TC, max(8, c))
     codes_p = _pad_to(codes.astype(jnp.int32), 1, tc)
     parts = pq_adc_slots_pallas(luts, codes_p, tc=tc, interpret=interpret)
-    return jnp.sum(parts[:, :, :c], axis=1)
+    return ordered_sum(parts[:, :, :c], axis=1)
 
 
 __all__ = ["pq_adc", "pq_adc_ref", "pq_adc_slots", "pq_adc_slots_tiled"]

@@ -1,12 +1,17 @@
 """PQ lookup-table (codebook) build kernel.
 
 lut[q, m, c] = ||query_sub[q, m] - centroid[m, c]||², expanded to
-q2 - 2·q·c + c2 so the cross term is a (TQ, dsub) @ (dsub, K) matmul.
-Grid: (Q tiles, M subspaces); each step keeps one subspace's centroid
-block (K, dsub) and a query-column block (TQ, dsub) in VMEM.
+q2 - 2·q·c + c2.  Grid: (Q tiles, M subspaces).  Each step takes the whole
+(TQ, d) query block, so no block is narrower than the (8, 128) tile: the
+cross term is (TQ, d) @ (d, K) against subspace m's centroids placed in
+rows m·dsub..(m+1)·dsub of a zero (d, K) matrix, and q2 sums the squares
+of those lanes only.  The output is laid out (Q, M·K), one lane-aligned
+(TQ, K) block per step.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -15,13 +20,15 @@ from jax.experimental import pallas as pl
 DEFAULT_TQ = 128
 
 
-def _lut_kernel(q_ref, c_ref, out_ref):
-    q = q_ref[...]                                   # (TQ, dsub)
-    c = c_ref[0]                                     # (K, dsub)
-    cross = jnp.dot(q, c.T, preferred_element_type=jnp.float32)   # (TQ, K)
-    q2 = jnp.sum(q * q, axis=-1, keepdims=True)      # (TQ, 1)
-    c2 = jnp.sum(c * c, axis=-1)[None, :]            # (1, K)
-    out_ref[:, 0, :] = q2 - 2.0 * cross + c2
+def _lut_kernel(q_ref, e_ref, c2_ref, out_ref, *, dsub: int):
+    m = pl.program_id(1)
+    q = q_ref[...]                                   # (TQ, d)
+    lane = jax.lax.broadcasted_iota(jnp.int32, q.shape, 1)
+    mine = (lane >= m * dsub) & (lane < (m + 1) * dsub)
+    q2 = jnp.sum(jnp.where(mine, q * q, 0.0), axis=-1, keepdims=True)
+    cross = jnp.dot(q, e_ref[0], precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32)            # (TQ, K)
+    out_ref[...] = q2 - 2.0 * cross + c2_ref[0]
 
 
 def pq_lut_pallas(
@@ -33,15 +40,22 @@ def pq_lut_pallas(
     q, d = queries.shape
     m, k, dsub = centroids.shape
     assert d == m * dsub and q % tq == 0
+    # (M, d, K): subspace m's centroids transposed into its own rows
+    emb = jnp.zeros((m, m, dsub, k), jnp.float32)
+    emb = emb.at[jnp.arange(m), jnp.arange(m)].set(
+        jnp.transpose(centroids, (0, 2, 1))).reshape(m, d, k)
+    c2 = jnp.sum(centroids * centroids, -1)[:, None, :]           # (M, 1, K)
 
-    return pl.pallas_call(
-        _lut_kernel,
+    out = pl.pallas_call(
+        functools.partial(_lut_kernel, dsub=dsub),
         grid=(q // tq, m),
         in_specs=[
-            pl.BlockSpec((tq, dsub), lambda i, mm: (i, mm)),
-            pl.BlockSpec((1, k, dsub), lambda i, mm: (mm, 0, 0)),
+            pl.BlockSpec((tq, d), lambda i, mm: (i, 0)),
+            pl.BlockSpec((1, d, k), lambda i, mm: (mm, 0, 0)),
+            pl.BlockSpec((1, 1, k), lambda i, mm: (mm, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((tq, 1, k), lambda i, mm: (i, mm, 0)),
-        out_shape=jax.ShapeDtypeStruct((q, m, k), jnp.float32),
+        out_specs=pl.BlockSpec((tq, k), lambda i, mm: (i, mm)),
+        out_shape=jax.ShapeDtypeStruct((q, m * k), jnp.float32),
         interpret=interpret,
-    )(queries, centroids)
+    )(queries, emb, c2)
+    return out.reshape(q, m, k)

@@ -18,7 +18,9 @@ def pq_lut(
     tq: int | None = None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """(Q, d) x (M, K, dsub) -> (Q, M, K).  Drop-in for pq.build_lut."""
+    """(Q, d) x (M, K, dsub) -> (Q, M, K) plain subspace tables
+    ||q_m - c_mk||².  ``pq.build_lut``'s residual rows are these tables of
+    the residual centroids less ||q_m||²."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     q = queries.shape[0]

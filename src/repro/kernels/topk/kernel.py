@@ -7,8 +7,11 @@ compare-exchange stages, each a full-width vector op (no data-dependent
 control flow).  Indices ride along; ties break by index so the kernel is a
 permutation (required for the dedup logic upstream).
 
-The XOR-partner exchange is expressed as a reshape to (..., C/2j, 2, j) and a
-flip of the 2-axis — both Mosaic-supported layout ops.
+The partner of lane i in a stage of distance j is lane i XOR j: i + j in the
+lower half of each pair, i - j in the upper.  Both come from lane rotations
+(``pltpu.roll``, which Mosaic lowers to a native rotate).  Rotating the lane
+index alongside the data says which rotation brought the partner, so the
+exchange does not depend on the rotation's direction convention.
 """
 
 from __future__ import annotations
@@ -18,24 +21,28 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_TB = 8  # rows per tile
 
 
 def _bitonic_stage(vals, idxs, kk: int, jj: int):
     b, c = vals.shape
-    v4 = vals.reshape(b, c // (2 * jj), 2, jj)
-    i4 = idxs.reshape(b, c // (2 * jj), 2, jj)
-    pv = jnp.flip(v4, axis=2).reshape(b, c)          # partner = index XOR jj
-    pi = jnp.flip(i4, axis=2).reshape(b, c)
-
     lane = jax.lax.broadcasted_iota(jnp.int32, (b, c), 1)
-    asc = (lane & kk) == 0                            # ascending block?
     lower = (lane & jj) == 0                          # lower half of pair?
-    take_min = asc == lower
+    partner = jnp.where(lower, lane + jj, lane - jj)  # = lane XOR jj
+    from_a = pltpu.roll(lane, jj, 1) == partner       # which rotation
+    pv = jnp.where(from_a, pltpu.roll(vals, jj, 1), pltpu.roll(vals, c - jj, 1))
+    pi = jnp.where(from_a, pltpu.roll(idxs, jj, 1), pltpu.roll(idxs, c - jj, 1))
 
+    # ascending block == lower half of the pair -> keep the min (bits of
+    # the lane index compared as ints: Mosaic has no i1 == i1)
+    take_min = ((lane // kk) & 1) == ((lane // jj) & 1)
     a_less = (vals < pv) | ((vals == pv) & (idxs < pi))
-    keep = jnp.where(take_min, a_less, ~a_less)
+    a_more = (vals > pv) | ((vals == pv) & (idxs > pi))
+    # identical (val, idx) pairs (padding) keep neither: the partner copy
+    # is the same entry.  No select over booleans: Mosaic refuses it.
+    keep = (take_min & a_less) | (~take_min & a_more)
     return (
         jnp.where(keep, vals, pv),
         jnp.where(keep, idxs, pi),

@@ -134,11 +134,8 @@ def _decode_cell(cfg, shape, mesh, multi_pod, unroll=True,
 def _batann_cell(mesh, multi_pod, sector: bool = False):
     """The paper's own serve workload: the baton SPMD search over the full
     flattened device set (each device = one partition/server)."""
-    import numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
     from repro.configs.batann_serve import CONFIG as BC
-    from repro.core import baton
+    from repro.core import baton, pq
     from repro.core.beam_search import Shard
     from repro.launch.mesh import all_axes
 
@@ -152,6 +149,7 @@ def _batann_cell(mesh, multi_pod, sector: bool = False):
     )
     q_per_dev = cfg.slots  # one refill's worth of queued queries per device
     d = BC.dim
+    w = pq.code_width(BC.pq_m)     # code bytes == table rows
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype)
@@ -159,14 +157,14 @@ def _batann_cell(mesh, multi_pod, sector: bool = False):
     dev = baton.DeviceState(
         states=jax.eval_shape(
             lambda: baton._batched_empty_states(
-                d, cfg, (n_dev, cfg.slots), m=BC.pq_m, k_pq=BC.pq_k
+                d, cfg, (n_dev, cfg.slots), m=w, k_pq=BC.pq_k
             )
         ),
         queue_emb=sds((n_dev, q_per_dev, d), jnp.float32),
         queue_qid=sds((n_dev, q_per_dev), jnp.int32),
         queue_starts=sds((n_dev, q_per_dev, cfg.n_starts), jnp.int32),
         queue_start_d=sds((n_dev, q_per_dev, cfg.n_starts), jnp.float32),
-        queue_lut=sds((n_dev, q_per_dev, BC.pq_m, BC.pq_k), jnp.float32),
+        queue_lut=sds((n_dev, q_per_dev, w, BC.pq_k), jnp.float32),
         queue_head=sds((n_dev,), jnp.int32),
         out_ids=sds((n_dev, q_per_dev, cfg.k), jnp.int32),
         out_dists=sds((n_dev, q_per_dev, cfg.k), jnp.float32),
@@ -181,50 +179,22 @@ def _batann_cell(mesh, multi_pod, sector: bool = False):
         shard = Shard(
             vectors=sds((n_dev, n_local, d), jnp.uint8),
             neighbors=sds((n_dev, n_local, BC.graph_r), jnp.int32),
-            codes=sds((1, BC.pq_m), jnp.uint8),
+            codes=sds((1, w), jnp.uint8),
             node2part=sds((BC.n_total,), jnp.uint8),
             node2local=sds((BC.n_total,), jnp.int32),
-            nbr_codes=sds((n_dev, n_local, BC.graph_r, BC.pq_m), jnp.uint8),
+            nbr_codes=sds((n_dev, n_local, BC.graph_r, w), jnp.uint8),
         )
     else:
         shard = Shard(
             vectors=sds((n_dev, n_local, d), jnp.float32),
             neighbors=sds((n_dev, n_local, BC.graph_r), jnp.int32),
-            codes=sds((BC.n_total, BC.pq_m), jnp.uint8),
+            codes=sds((BC.n_total, w), jnp.uint8),
             node2part=sds((BC.n_total,), jnp.int32),
             node2local=sds((BC.n_total,), jnp.int32),
         )
-    codebook = sds((BC.pq_m, BC.pq_k, BC.dim // BC.pq_m), jnp.float32)
+    codebook = sds(pq.codebook_shape(BC.pq_m, BC.pq_k, BC.dim), jnp.float32)
 
-    fn = baton.make_spmd_fn(cfg, n_parts=n_dev, axis_name=axes)
-
-    def body(dv, s, cb):
-        dv1 = jax.tree.map(lambda x: x[0], dv)
-        s1 = Shard(s.vectors[0], s.neighbors[0], s.codes, s.node2part,
-                   s.node2local,
-                   s.nbr_codes[0] if s.nbr_codes is not None else None)
-        out = fn(dv1, s1, cb)
-        return jax.tree.map(lambda x: x[None], out)
-
-    dev_specs = jax.tree.map(lambda _: P(axes), dev)
-    shard_specs = Shard(vectors=P(axes), neighbors=P(axes), codes=P(),
-                        node2part=P(), node2local=P(),
-                        nbr_codes=P(axes) if sector else None)
-    from repro.compat import shard_map as _shard_map
-    smfn = _shard_map(
-        body, mesh=mesh, in_specs=(dev_specs, shard_specs, P()),
-        out_specs=dev_specs, check=False,
-    )
-    named = lambda tree: jax.tree.map(
-        lambda s: NamedSharding(mesh, s), tree,
-        is_leaf=lambda x: isinstance(x, P),
-    )
-    jitted = jax.jit(
-        smfn,
-        in_shardings=(named(dev_specs), named(shard_specs),
-                      NamedSharding(mesh, P())),
-        donate_argnums=(0,),
-    )
+    jitted = baton.spmd_program(cfg, mesh, axes, sector_codes=sector)
     return jitted, (dev, shard, codebook)
 
 

@@ -21,6 +21,7 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro import compile_cache
 from repro.api import Deployment
 from repro.configs.registry import get_serve_config, serve_config_ids
 
@@ -199,6 +200,7 @@ def config_from_args(args):
 
 
 def main():
+    compile_cache.enable()
     ap = build_argparser()
     args = ap.parse_args()
     try:

@@ -37,6 +37,7 @@ import queue as _queue
 import threading
 import time
 
+import jax
 import numpy as np
 
 from repro.core.state import STAT_FIELDS, envelope_bytes
@@ -121,6 +122,12 @@ class AsyncServingTier:
                  sector_codes: "bool | None" = None):
         if mode not in ("thread", "process"):
             raise ValueError(f"mode must be thread|process: {mode}")
+        if mode == "process" and jax.default_backend() != "cpu":
+            # this process already holds the accelerator: spawned workers
+            # could not open it and would serve from another backend
+            raise ValueError(
+                f"process mode runs only on the CPU backend (this process "
+                f"holds the {jax.default_backend()}); use mode='thread'")
         if not 1 <= n_workers <= index.p:
             raise ValueError(
                 f"n_workers must be in [1, p={index.p}]: {n_workers}")

@@ -183,9 +183,6 @@ def process_worker_main(wid, owned, shard_arrays, codebook_np, cfg_dict,
     ``runtime.partition_shard``; ``cfg_dict`` is the ``BatonParams``
     field dict (plain scalars, pickles fine).
     """
-    import os
-
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax.numpy as jnp
 
     from repro.core.baton import BatonParams

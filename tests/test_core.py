@@ -16,6 +16,51 @@ def test_brute_force_is_exact(dataset):
     assert np.array_equal(np.sort(naive), np.sort(dataset.gt[:4]))
 
 
+@pytest.mark.parametrize("n,q,k,d", [
+    (1, 3, 1, 8), (37, 5, 10, 96), (1500, 64, 17, 96), (20000, 33, 10, 32),
+    (300, 2100, 5, 8),
+])
+def test_exact_knn_matches_numpy(n, q, k, d):
+    """The jitted, tiled kNN == a plain numpy sort of the full distance
+    matrix; a swap is accepted only between (near-)equal distances."""
+    rng = np.random.default_rng(n + q)
+    vecs = rng.normal(size=(n, d)).astype(np.float32)
+    qs = rng.normal(size=(q, d)).astype(np.float32)
+    ids, dists = ref.exact_knn(vecs, qs, k)
+    full = ((qs[:, None, :].astype(np.float64) - vecs[None]) ** 2).sum(-1)
+    naive = np.argsort(full, axis=1, kind="stable")[:, :k]
+    want = np.take_along_axis(full, naive, 1)
+    assert ids.shape == dists.shape == (q, k)
+    np.testing.assert_allclose(dists, want, rtol=1e-4, atol=1e-4)
+    swapped = ids != naive
+    got = np.take_along_axis(full, ids, 1)
+    np.testing.assert_allclose(got[swapped], want[swapped], rtol=1e-5,
+                               atol=1e-5)
+    assert np.array_equal(ref.brute_force_knn(vecs, qs, k), ids)
+
+
+def test_chunked_reverse_prune_unchanged(dataset, monkeypatch):
+    """Pruning in width-grouped calls of at most PRUNE_ELEMS candidates
+    gives the same graph as one call over every row (rows are independent;
+    padding columns are never picked)."""
+    from repro.core import vamana
+
+    def one_call(vectors, jvec, neighbors, rows, cids, widths, r, alpha):
+        cd = vamana._exact_dists(vectors, vectors[rows], cids)
+        neighbors[rows] = np.asarray(vamana._robust_prune_batch(
+            jnp.asarray(vectors[rows]), jnp.asarray(cids), jnp.asarray(cd),
+            jvec, r=r, alpha=alpha))
+
+    knn = ref.brute_force_knn(dataset.vectors, dataset.vectors, 17)[:, 1:]
+    with monkeypatch.context() as m:
+        m.setattr(vamana, "_prune_rows", one_call)
+        whole = vamana.build_from_knn(dataset.vectors, knn, r=20)
+    monkeypatch.setattr(vamana, "PRUNE_ELEMS", 2048)
+    chunked = vamana.build_from_knn(dataset.vectors, knn, r=20)
+    assert np.array_equal(chunked.neighbors, whole.neighbors)
+    assert chunked.medoid == whole.medoid
+
+
 def test_pq_distance_correlation(dataset, codebook, codes):
     """ADC distances must track exact distances (the index's guidance signal)."""
     lut = pq.build_lut(codebook.centroids, jnp.asarray(dataset.queries[:8]))
@@ -26,12 +71,14 @@ def test_pq_distance_correlation(dataset, codebook, codes):
 
 
 def test_pq_reconstruction_consistency(dataset, codebook, codes):
-    """adc(q, code(x)) == ||q - reconstruct(code(x))||^2 by construction."""
+    """adc(q, code(x)) == ||q - reconstruct(code(x))||^2 + level_error
+    (the quantized cross term's error) by construction."""
     q = jnp.asarray(dataset.queries[:4])
     lut = pq.build_lut(codebook.centroids, q)
     approx = np.asarray(pq.adc(lut, jnp.asarray(codes[:50])))
     recon = np.asarray(pq.reconstruct(codebook, jnp.asarray(codes[:50])))
-    exact = ref.pairwise_sq_l2(dataset.queries[:4], recon)
+    off = np.asarray(pq.level_error(codebook, jnp.asarray(codes[:50])))
+    exact = ref.pairwise_sq_l2(dataset.queries[:4], recon) + off[None]
     np.testing.assert_allclose(approx, exact, rtol=1e-3, atol=1e-3)
 
 

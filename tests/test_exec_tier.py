@@ -281,6 +281,32 @@ def test_run_only_unknown_tag_one_line_error(monkeypatch, capsys):
     assert "\n" not in msg          # one line, no traceback
 
 
+def test_run_exits_nonzero_on_failed_suite(monkeypatch, tmp_path, capsys):
+    from benchmarks import run
+
+    def boom():
+        raise RuntimeError("suite broke")
+
+    monkeypatch.setattr(run, "ARTIFACTS", str(tmp_path))
+    monkeypatch.setattr(run, "_resolve", lambda spec: boom)
+    monkeypatch.setattr(sys, "argv", ["run.py", "--only", "fig3"])
+    with pytest.raises(SystemExit) as exc:
+        run.main()
+    assert exc.value.code not in (0, None)
+    assert "fig3_FAILED" in str(exc.value.code)
+    assert "fig3_FAILED" in (tmp_path / "bench.csv").read_text()
+
+
+def test_process_mode_refused_off_cpu(baton_index, exec_cfg, monkeypatch):
+    """A process that holds an accelerator cannot hand it to spawned
+    workers: process mode is refused up front, naming thread mode."""
+    from repro.serve_async import tier as tier_mod
+
+    monkeypatch.setattr(tier_mod.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="mode='thread'"):
+        AsyncServingTier(baton_index, exec_cfg, n_workers=2, mode="process")
+
+
 def test_fig20_suite_registered():
     from benchmarks import figures
     from benchmarks.run import SUITES

@@ -16,50 +16,23 @@ _SCRIPT = textwrap.dedent(
     """
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    import numpy as np, jax, jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
+    import numpy as np, jax
     from repro.data import synth
     from repro.core import ref, baton
-    from repro.core.beam_search import Shard
 
     ds = synth.make_dataset("deep", n=1200, n_queries=24, seed=1)
     idx = baton.build_index(ds.vectors, p=8, r=16, l_build=32, pq_m=16,
                             pq_k=128, head_fraction=0.03, seed=1)
     cfg = baton.BatonParams(L=32, W=4, k=10, pool=128, slots=16, pair_cap=4,
                             n_starts=4)
-    ids_sim, _, stats_sim = baton.run_simulated(idx, ds.queries, cfg)
+    ids_sim, d_sim, stats_sim = baton.run_simulated(idx, ds.queries, cfg)
 
     mesh = jax.make_mesh((8,), ("part",))
-    q_dev, qid_dev, st_dev, sd_dev, B, Bp, per = baton._split_round_robin(
-        idx, ds.queries, cfg)
-    codebook = jnp.asarray(idx.codebook)
-    devs = jax.vmap(
-        lambda q, i, s, sd: baton.init_device_state(q, i, s, sd, cfg,
-                                                    codebook))(
-        jnp.asarray(q_dev), jnp.asarray(qid_dev), jnp.asarray(st_dev),
-        jnp.asarray(sd_dev))
-    shard = idx.stacked_shards()
-    fn = baton.make_spmd_fn(cfg, n_parts=8, axis_name="part")
-
-    def body(d, s, c):
-        d1 = jax.tree.map(lambda x: x[0], d)
-        s1 = Shard(s.vectors[0], s.neighbors[0], s.codes, s.node2part,
-                   s.node2local)
-        out = fn(d1, s1, c)
-        return jax.tree.map(lambda x: x[None], out)
-
-    dev_specs = jax.tree.map(lambda _: P("part"), devs)
-    shard_specs = Shard(vectors=P("part"), neighbors=P("part"), codes=P(),
-                        node2part=P(), node2local=P())
-    from repro.compat import shard_map
-    smfn = shard_map(body, mesh=mesh,
-                     in_specs=(dev_specs, shard_specs, P()),
-                     out_specs=dev_specs, check=False)
-    out = jax.jit(smfn)(devs, shard, codebook)
-    ids_spmd, _, stats_spmd = baton._collect(out, qid_dev, cfg, B, Bp, 8,
-                                             per, 0)
+    ids_spmd, d_spmd, stats_spmd = baton.run_spmd(idx, ds.queries, cfg, mesh)
+    assert stats_spmd["part_device"] == list(range(8)), stats_spmd
     assert stats_spmd["delivered"] == 1.0, stats_spmd["delivered"]
     assert np.array_equal(ids_sim, ids_spmd), "sim/spmd mismatch"
+    assert np.array_equal(d_sim, d_spmd), "sim/spmd distances differ"
     rec = ref.recall_at_k(ids_spmd, ds.gt, 10)
     assert rec > 0.8, rec
     print("SPMD-EQUIV-OK", rec)
