@@ -46,7 +46,8 @@ class SearchResult:
     """Uniform search output: ids/dists plus the engine's stats dict.
 
     ``stats`` always contains the ``STAT_KEYS`` per-query counter arrays;
-    engines may add extras (baton: ``trace``/``n_supersteps``/``delivered``;
+    engines may add extras (baton: ``trace``/``n_supersteps``/
+    ``local_steps``/``adc_rows``/``delivered``;
     scatter-gather: ``max_part_hops`` and per-partition branch counters).
     """
 

@@ -49,11 +49,30 @@ The same per-device functions are driven two ways: ``run_simulated`` (vmap
 over the partition axis — single-host benchmarks; bit-identical math) and
 ``run_spmd`` (``make_spmd_fn`` under shard_map over a real mesh axis, one
 partition per device — multi-device runs and the 512-chip dry-run).
+
+What a profiler sees (nothing is recorded unless one runs):
+
+* every device op of the search program carries one of the ``PHASES`` as a
+  ``jax.named_scope`` in its ``op_name``; a scope opened around a loop names
+  the loop's own control, and inside the loop the innermost scope names an
+  op;
+* each stage of a search call is a host span ``baton:<stage>`` (``head``,
+  ``split``, ``init``, ``upload``, ``supersteps``, ``collect``) whose
+  ``call`` argument numbers the calls of this process;
+* ``stats`` counts ``n_supersteps``, ``local_steps`` (local-advance loop
+  bodies: per super-step, the most any partition ran — under vmap every
+  body runs all P partitions, under SPMD the others wait for the slowest at
+  the exchange; the device states carry it as ``DeviceState.local_steps``)
+  and ``adc_rows`` (``local_steps`` x the P·S·W·R candidate rows each such
+  body scores).
 """
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
+import itertools
 import math
 import time
 from functools import lru_cache, partial
@@ -71,6 +90,10 @@ from repro.core.beam_search import (
 from repro.core.state import (
     INF, N_STATS, N_TRACE, NO_ID, Counters, HopTrace, QueryState, empty_state,
 )
+
+# the named scopes of the search program's device ops, in super-step order
+PHASES = ("refill", "frontier", "sectors", "adc", "merge", "deliver",
+          "exchange")
 
 
 # ---------------------------------------------------------------------------
@@ -288,6 +311,7 @@ class DeviceState(NamedTuple):
     out_stats: jnp.ndarray     # (Q, N_STATS) — see state.STAT_FIELDS
     out_trace: jnp.ndarray     # (Q, T, N_TRACE) — see state.TRACE_FIELDS
     delivered: jnp.ndarray     # (Q,) bool
+    local_steps: jnp.ndarray   # () local-advance bodies so far (docstring)
 
 
 class ResultMsg(NamedTuple):
@@ -348,6 +372,7 @@ def init_device_state(queries, qids, starts, start_d, cfg: BatonParams,
         out_stats=jnp.zeros((q, N_STATS), jnp.int32),
         out_trace=jnp.full((q, cfg.trace_cap, N_TRACE), -1, jnp.int32),
         delivered=jnp.zeros((q,), bool),
+        local_steps=jnp.int32(0),
     )
 
 
@@ -442,7 +467,8 @@ def local_advance(dev: DeviceState, shard: Shard, cfg: BatonParams, my_part):
 
     Per-slot LUTs come from ``states.lut`` (built once per query).  The
     default body is the fused slot-batched step; ``cfg.fused=False`` selects
-    the per-slot reference path (bit-identical results)."""
+    the per-slot reference path (bit-identical results).  Returns (devices,
+    the loop bodies run)."""
 
     def frontier(st):
         return _frontier_ownership(st, shard, cfg, my_part)
@@ -484,15 +510,16 @@ def local_advance(dev: DeviceState, shard: Shard, cfg: BatonParams, my_part):
         states, ran = step_all(states)
         return states, it + 1, jnp.any(ran)
 
-    states, _, _ = jax.lax.while_loop(
-        cond, body, (dev.states, jnp.int32(0), jnp.asarray(True))
-    )
-
     def finalize(st):
         _, _, v = select_frontier(st.beam_ids, st.beam_expl, 1)
         return st._replace(done=st.done | (st.active & ~jnp.any(v)))
 
-    return dev._replace(states=jax.vmap(finalize)(states))
+    with jax.named_scope("frontier"):
+        states, steps, _ = jax.lax.while_loop(
+            cond, body, (dev.states, jnp.int32(0), jnp.asarray(True))
+        )
+        states = jax.vmap(finalize)(states)
+    return dev._replace(states=states), steps
 
 
 def deliver_local(dev: DeviceState, cfg: BatonParams, my_part, n_parts: int):
@@ -732,27 +759,50 @@ def _superstep_local(dev, shard, cfg, my_part, n_parts, codebook=None):
 
     No per-super-step LUT build: every resident state carries its own LUT
     (seeded at refill from the once-per-query queue build, or built at
-    refill under ``cfg.lazy_queue_lut``)."""
-    dev = refill(dev, cfg, my_part, codebook=codebook)
-    pre = dev.states.counters
-    dev = local_advance(dev, shard, cfg, my_part)
-    dev = _trace_accumulate(dev, pre)
-    dev = deliver_local(dev, cfg, my_part, n_parts)
-    res_buf, dev = pack_results(dev, cfg, my_part, n_parts)
-    dest = plan_routes(dev, shard, cfg, my_part)                 # (S,)
-    want = jnp.zeros((n_parts,), jnp.int32).at[
-        jnp.where(dest >= 0, dest, 0)
-    ].add((dest >= 0).astype(jnp.int32))
-    # conservative: a state counts as occupying its slot until actually sent
-    free = cfg.slots - jnp.sum(dev.states.active.astype(jnp.int32))
-    n_active = jnp.sum(dev.states.active.astype(jnp.int32))
-    n_queue = jnp.maximum(dev.queue_qid.shape[0] - dev.queue_head, 0)
-    return dev, res_buf, dest, want, free, n_active + n_queue
+    refill under ``cfg.lazy_queue_lut``).  The last of its results is the
+    number of local-advance loop bodies run."""
+    with jax.named_scope("refill"):
+        dev = refill(dev, cfg, my_part, codebook=codebook)
+        pre = dev.states.counters
+    dev, steps = local_advance(dev, shard, cfg, my_part)
+    with jax.named_scope("deliver"):
+        dev = _trace_accumulate(dev, pre)
+        dev = deliver_local(dev, cfg, my_part, n_parts)
+        res_buf, dev = pack_results(dev, cfg, my_part, n_parts)
+    with jax.named_scope("exchange"):
+        dest = plan_routes(dev, shard, cfg, my_part)             # (S,)
+        want = jnp.zeros((n_parts,), jnp.int32).at[
+            jnp.where(dest >= 0, dest, 0)
+        ].add((dest >= 0).astype(jnp.int32))
+        # conservative: a state counts as occupying its slot until sent
+        free = cfg.slots - jnp.sum(dev.states.active.astype(jnp.int32))
+        n_active = jnp.sum(dev.states.active.astype(jnp.int32))
+        n_queue = jnp.maximum(dev.queue_qid.shape[0] - dev.queue_head, 0)
+        remaining = n_active + n_queue
+    return dev, res_buf, dest, want, free, remaining, steps
 
 
 # ---------------------------------------------------------------------------
 # drivers
 # ---------------------------------------------------------------------------
+
+_CALLS = itertools.count()
+_CALL = contextvars.ContextVar("baton_call", default=-1)
+
+
+@contextlib.contextmanager
+def _numbered_call():
+    """Number one search call for the ``call`` argument of its spans."""
+    token = _CALL.set(next(_CALLS))
+    try:
+        yield
+    finally:
+        _CALL.reset(token)
+
+
+def _stage(name: str):
+    """The host span ``baton:<name>`` of the current search call."""
+    return jax.profiler.TraceAnnotation(f"baton:{name}", call=_CALL.get())
 
 
 def _split_round_robin(index, queries, cfg):
@@ -763,22 +813,33 @@ def _split_round_robin(index, queries, cfg):
         queries = np.concatenate([queries, queries[:pad]], 0)
     Bp = queries.shape[0]
     qids = np.arange(Bp, dtype=np.int32)
-    starts, start_dists = index.head_starts(queries, cfg.n_starts)
-    per = Bp // P
-    q_dev = np.zeros((P, per, queries.shape[1]), np.float32)
-    qid_dev = np.full((P, per), -1, np.int32)
-    st_dev = np.full((P, per, cfg.n_starts), NO_ID, np.int32)
-    sd_dev = np.full((P, per, cfg.n_starts), np.inf, np.float32)
-    for d in range(P):
-        sel = qids[qids % P == d]
-        q_dev[d, : len(sel)] = queries[sel]
-        qid_dev[d, : len(sel)] = sel
-        st_dev[d, : len(sel)] = starts[sel]
-        sd_dev[d, : len(sel)] = start_dists[sel]
+    with _stage("head"):
+        starts, start_dists = index.head_starts(queries, cfg.n_starts)
+    with _stage("split"):
+        per = Bp // P
+        q_dev = np.zeros((P, per, queries.shape[1]), np.float32)
+        qid_dev = np.full((P, per), -1, np.int32)
+        st_dev = np.full((P, per, cfg.n_starts), NO_ID, np.int32)
+        sd_dev = np.full((P, per, cfg.n_starts), np.inf, np.float32)
+        for d in range(P):
+            sel = qids[qids % P == d]
+            q_dev[d, : len(sel)] = queries[sel]
+            qid_dev[d, : len(sel)] = sel
+            st_dev[d, : len(sel)] = starts[sel]
+            sd_dev[d, : len(sel)] = start_dists[sel]
     return q_dev, qid_dev, st_dev, sd_dev, B, Bp, per
 
 
-def _collect(devs, qid_dev, cfg, B, Bp, P, per, n_supersteps):
+def _step_counts(index: BatonIndex, cfg: BatonParams, n_supersteps,
+                 devs: DeviceState) -> dict:
+    """The program's step counters as ``stats`` entries (module docstring)."""
+    rows = index.p * cfg.slots * cfg.W * index.part_neighbors.shape[-1]
+    local_steps = int(np.max(np.asarray(devs.local_steps)))
+    return {"n_supersteps": int(n_supersteps), "local_steps": local_steps,
+            "adc_rows": local_steps * rows}
+
+
+def _collect(devs, qid_dev, cfg, B, Bp, P, per, counts):
     from repro.core.state import STAT_FIELDS
 
     out_ids = np.asarray(devs.out_ids).reshape(P * per, -1)
@@ -800,7 +861,7 @@ def _collect(devs, qid_dev, cfg, B, Bp, P, per, n_supersteps):
     ids, dists, stats = ids[:B], dists[:B], stats[:B]
     out = {f: stats[:, i] for i, f in enumerate(STAT_FIELDS)}
     out["trace"] = trace[:B]
-    out["n_supersteps"] = int(n_supersteps)
+    out.update(counts)
     out["delivered"] = float(np.asarray(devs.delivered).mean())
     return ids, dists, out
 
@@ -812,11 +873,17 @@ def run_simulated(index: BatonIndex, queries: np.ndarray, cfg: BatonParams,
     Bit-identical math to the SPMD path; the measurement substrate for every
     paper figure (counters are exact; time comes from io_sim's cost model).
     """
-    devs, codebook, split = _initial_devices(index, queries, cfg)
-    shard = index.stacked_shards(sector_codes=sector_codes)
-    devs, n_supersteps = run_supersteps(devs, shard, codebook, cfg=cfg,
-                                        P=index.p)
-    return _collect(devs, *split, n_supersteps)
+    with _numbered_call():
+        devs, codebook, split = _initial_devices(index, queries, cfg)
+        with _stage("upload"):
+            shard = jax.block_until_ready(
+                index.stacked_shards(sector_codes=sector_codes))
+        with _stage("supersteps"):
+            devs, n_supersteps = jax.block_until_ready(
+                run_supersteps(devs, shard, codebook, cfg=cfg, P=index.p))
+        with _stage("collect"):
+            return _collect(devs, *split, _step_counts(
+                index, cfg, n_supersteps, devs))
 
 
 def _initial_devices(index: BatonIndex, queries: np.ndarray, cfg: BatonParams):
@@ -824,13 +891,14 @@ def _initial_devices(index: BatonIndex, queries: np.ndarray, cfg: BatonParams):
     states, codebook, the arguments :func:`_collect` needs besides them)."""
     q_dev, qid_dev, st_dev, sd_dev, B, Bp, per = _split_round_robin(
         index, queries, cfg)
-    codebook = jnp.asarray(index.codebook)
-    devs = jax.vmap(
-        lambda q, i, s, sd: init_device_state(q, i, s, sd, cfg, codebook)
-    )(
-        jnp.asarray(q_dev), jnp.asarray(qid_dev), jnp.asarray(st_dev),
-        jnp.asarray(sd_dev)
-    )
+    with _stage("init"):
+        codebook = jnp.asarray(index.codebook)
+        devs = jax.block_until_ready(jax.vmap(
+            lambda q, i, s, sd: init_device_state(q, i, s, sd, cfg, codebook)
+        )(
+            jnp.asarray(q_dev), jnp.asarray(qid_dev), jnp.asarray(st_dev),
+            jnp.asarray(sd_dev)
+        ))
     return devs, codebook, (qid_dev, cfg, B, Bp, index.p, per)
 
 
@@ -841,41 +909,46 @@ def run_supersteps(devs: DeviceState, shard: Shard, codebook, cfg: BatonParams,
 
     The index arrives as arguments (``shard``, ``codebook``), never as
     constants baked into the program, so one compilation per (cfg, P,
-    shapes) serves every call.  Returns (final devices, super-step count).
+    shapes) serves every call.  Returns (final devices, super-step count);
+    each super-step adds the local-advance bodies it ran (each runs all P
+    partitions) to every device's ``local_steps``.
     """
-    my_parts = jnp.arange(P, dtype=jnp.int32)
     shard_axes = Shard(vectors=0, neighbors=0, codes=None, node2part=None,
                        node2local=None,
                        nbr_codes=None if shard.nbr_codes is None else 0)
 
     def superstep(devs):
-        devs, res_buf, dest, want, free, remaining = jax.vmap(
+        devs, res_buf, dest, want, free, remaining, steps = jax.vmap(
             lambda dv, sh, mp: _superstep_local(dv, sh, cfg, mp, P,
                                                 codebook=codebook),
             in_axes=(0, shard_axes, 0),
-        )(devs, shard, my_parts)
-        grant = grant_matrix(want, free, cfg.pair_cap)           # (P, P)
-        bufs, devs = jax.vmap(
-            lambda dv, de, gr: pack_sends(dv, de, gr, cfg, P)
-        )(devs, dest, grant)
-        # all_to_all == transpose of the (src, dst) axes in simulation
-        inc_states = jax.tree.map(
-            lambda x: jnp.swapaxes(x, 0, 1).reshape(
-                (P, P * cfg.pair_cap) + x.shape[3:]
-            ),
-            bufs,
-        )
-        inc_res = jax.tree.map(
-            lambda x: jnp.swapaxes(x, 0, 1).reshape(
-                (P, P * cfg.result_cap) + x.shape[3:]
-            ),
-            res_buf,
-        )
-        devs = jax.vmap(
-            lambda dv, inc: merge_recv(dv, inc, cfg, codebook)
-        )(devs, inc_states)
-        devs = jax.vmap(lambda dv, inc: merge_results(dv, inc, cfg, P))(devs, inc_res)
-        return devs, jnp.sum(remaining)
+        )(devs, shard, jnp.arange(P, dtype=jnp.int32))
+        with jax.named_scope("exchange"):
+            grant = grant_matrix(want, free, cfg.pair_cap)       # (P, P)
+            bufs, devs = jax.vmap(
+                lambda dv, de, gr: pack_sends(dv, de, gr, cfg, P)
+            )(devs, dest, grant)
+            # all_to_all == transpose of the (src, dst) axes in simulation
+            inc_states = jax.tree.map(
+                lambda x: jnp.swapaxes(x, 0, 1).reshape(
+                    (P, P * cfg.pair_cap) + x.shape[3:]
+                ),
+                bufs,
+            )
+            inc_res = jax.tree.map(
+                lambda x: jnp.swapaxes(x, 0, 1).reshape(
+                    (P, P * cfg.result_cap) + x.shape[3:]
+                ),
+                res_buf,
+            )
+            devs = jax.vmap(
+                lambda dv, inc: merge_recv(dv, inc, cfg, codebook)
+            )(devs, inc_states)
+            devs = jax.vmap(
+                lambda dv, inc: merge_results(dv, inc, cfg, P))(devs, inc_res)
+            # the vmapped local advance ran as many bodies as its longest
+            devs = devs._replace(local_steps=devs.local_steps + jnp.max(steps))
+            return devs, jnp.sum(remaining)
 
     def cond(c):
         _, it, rem = c
@@ -886,8 +959,9 @@ def run_supersteps(devs: DeviceState, shard: Shard, codebook, cfg: BatonParams,
         devs, rem = superstep(devs)
         return devs, it + 1, rem
 
-    devs, n_supersteps, _ = jax.lax.while_loop(
-        cond, body, (devs, jnp.int32(0), jnp.int32(1)))
+    with jax.named_scope("exchange"):
+        devs, n_supersteps, _ = jax.lax.while_loop(
+            cond, body, (devs, jnp.int32(0), jnp.int32(1)))
     return devs, n_supersteps
 
 
@@ -895,44 +969,50 @@ def make_spmd_fn(cfg: BatonParams, n_parts: int, axis_name: str = "part"):
     """shard_map body for a mesh axis of size n_parts.
 
     dev: per-device state (sharded on axis 0 outside), shard: per-partition
-    leaves sharded, maps/codes replicated.  Returns final DeviceState.
+    leaves sharded, maps/codes replicated.  Returns (final DeviceState,
+    super-step count), as :func:`run_supersteps` does: each super-step adds
+    the local-advance bodies of its slowest partition to ``local_steps``.
     """
 
-    def fn(dev: DeviceState, shard: Shard, codebook) -> DeviceState:
-        my_part = jax.lax.axis_index(axis_name).astype(jnp.int32)
-
+    def fn(dev: DeviceState, shard: Shard, codebook):
         def cond(c):
             _, it, rem = c
             return (rem > 0) & (it < cfg.max_supersteps)
 
         def body(c):
             dev, it, _ = c
-            dev, res_buf, dest, want, free, remaining = _superstep_local(
-                dev, shard, cfg, my_part, n_parts, codebook=codebook
-            )
-            want_all = jax.lax.all_gather(want, axis_name)       # (P, P)
-            free_all = jax.lax.all_gather(free, axis_name)       # (P,)
-            grant = grant_matrix(want_all, free_all, cfg.pair_cap)
-            buf, dev = pack_sends(dev, dest, grant[my_part], cfg, n_parts)
-            inc = jax.tree.map(
-                lambda x: jax.lax.all_to_all(
-                    x, axis_name, split_axis=0, concat_axis=0, tiled=True
-                ).reshape((n_parts * cfg.pair_cap,) + x.shape[2:]),
-                buf,
-            )
-            inc_res = jax.tree.map(
-                lambda x: jax.lax.all_to_all(
-                    x, axis_name, split_axis=0, concat_axis=0, tiled=True
-                ).reshape((n_parts * cfg.result_cap,) + x.shape[2:]),
-                res_buf,
-            )
-            dev = merge_recv(dev, inc, cfg, codebook)
-            dev = merge_results(dev, inc_res, cfg, n_parts)
-            rem = jax.lax.psum(remaining, axis_name)
+            dev, res_buf, dest, want, free, remaining, ran = \
+                _superstep_local(dev, shard, cfg, my_part, n_parts,
+                                 codebook=codebook)
+            with jax.named_scope("exchange"):
+                want_all = jax.lax.all_gather(want, axis_name)   # (P, P)
+                free_all = jax.lax.all_gather(free, axis_name)   # (P,)
+                grant = grant_matrix(want_all, free_all, cfg.pair_cap)
+                buf, dev = pack_sends(dev, dest, grant[my_part], cfg, n_parts)
+                inc = jax.tree.map(
+                    lambda x: jax.lax.all_to_all(
+                        x, axis_name, split_axis=0, concat_axis=0, tiled=True
+                    ).reshape((n_parts * cfg.pair_cap,) + x.shape[2:]),
+                    buf,
+                )
+                inc_res = jax.tree.map(
+                    lambda x: jax.lax.all_to_all(
+                        x, axis_name, split_axis=0, concat_axis=0, tiled=True
+                    ).reshape((n_parts * cfg.result_cap,) + x.shape[2:]),
+                    res_buf,
+                )
+                dev = merge_recv(dev, inc, cfg, codebook)
+                dev = merge_results(dev, inc_res, cfg, n_parts)
+                rem = jax.lax.psum(remaining, axis_name)
+                dev = dev._replace(local_steps=dev.local_steps
+                                   + jax.lax.pmax(ran, axis_name))
             return dev, it + 1, rem
 
-        dev, _, _ = jax.lax.while_loop(cond, body, (dev, jnp.int32(0), jnp.int32(1)))
-        return dev
+        with jax.named_scope("exchange"):
+            my_part = jax.lax.axis_index(axis_name).astype(jnp.int32)
+            dev, n_supersteps, _ = jax.lax.while_loop(
+                cond, body, (dev, jnp.int32(0), jnp.int32(1)))
+        return dev, n_supersteps
 
     return fn
 
@@ -957,9 +1037,10 @@ def spmd_program(cfg: BatonParams, mesh, axis_name="part",
     """The search as one SPMD program: :func:`make_spmd_fn` under
     ``shard_map``, partition p on the p-th device along ``axis_name``.
 
-    ``(devs, shard, codebook) -> devs``, with the stacked device states and
-    the per-partition shard leaves split along their leading axis and the
-    rest replicated (the layout of :func:`run_supersteps`' arguments).
+    ``(devs, shard, codebook) -> (devs, n_supersteps)``, with the stacked
+    device states and the per-partition shard leaves split along their
+    leading axis and the rest replicated (the layout of
+    :func:`run_supersteps`' arguments), and the count replicated.
     Cached per (cfg, mesh, axes), so repeated calls reuse one compilation.
     """
     fn = make_spmd_fn(cfg, n_parts=_axis_size(mesh, axis_name),
@@ -969,14 +1050,15 @@ def spmd_program(cfg: BatonParams, mesh, axis_name="part",
         s1 = s._replace(
             vectors=s.vectors[0], neighbors=s.neighbors[0],
             nbr_codes=None if s.nbr_codes is None else s.nbr_codes[0])
-        out = fn(jax.tree.map(lambda x: x[0], dv), s1, cb)
-        return jax.tree.map(lambda x: x[None], out)
+        out, n_supersteps = fn(jax.tree.map(lambda x: x[0], dv), s1, cb)
+        return jax.tree.map(lambda x: x[None], out), n_supersteps
 
     shardings = _spmd_shardings(mesh, axis_name, sector_codes)
     specs = jax.tree.map(lambda s: s.spec, shardings,
                          is_leaf=lambda x: isinstance(x, NamedSharding))
     return jax.jit(
-        jax.shard_map(body, mesh=mesh, in_specs=specs, out_specs=specs[0],
+        jax.shard_map(body, mesh=mesh, in_specs=specs,
+                      out_specs=(specs[0], PSpec()),
                       check_vma=False),
         in_shardings=shardings, donate_argnums=(0,))
 
@@ -992,12 +1074,19 @@ def run_spmd(index: BatonIndex, queries: np.ndarray, cfg: BatonParams, mesh):
     if mesh.size != index.p:
         raise ValueError(f"{index.p} partitions on {mesh.size} devices")
     axes = tuple(mesh.axis_names)
-    devs, codebook, split = _initial_devices(index, queries, cfg)
-    args = jax.device_put((devs, index.stacked_shards(), codebook),
-                          _spmd_shardings(mesh, axes, False))
-    placed = {s.index[0].start or 0: s.device.id
-              for s in args[1].vectors.addressable_shards}
-    out = spmd_program(cfg, mesh, axes)(*args)
-    ids, dists, stats = _collect(out, *split, 0)
+    with _numbered_call():
+        devs, codebook, split = _initial_devices(index, queries, cfg)
+        with _stage("upload"):
+            args = jax.block_until_ready(jax.device_put(
+                (devs, index.stacked_shards(), codebook),
+                _spmd_shardings(mesh, axes, False)))
+        placed = {s.index[0].start or 0: s.device.id
+                  for s in args[1].vectors.addressable_shards}
+        with _stage("supersteps"):
+            out, n_supersteps = jax.block_until_ready(
+                spmd_program(cfg, mesh, axes)(*args))
+        with _stage("collect"):
+            ids, dists, stats = _collect(out, *split, _step_counts(
+                index, cfg, n_supersteps, out))
     stats["part_device"] = [placed.get(p) for p in range(index.p)]
     return ids, dists, stats

@@ -329,70 +329,74 @@ def step_disk(
     reference path the fused merges are equivalence-tested against).
     """
     W = frontier_mask.shape[0]
-    gids = jnp.where(frontier_mask, state.beam_ids[frontier_pos], NO_ID)
+    with jax.named_scope("sectors"):
+        gids = jnp.where(frontier_mask, state.beam_ids[frontier_pos], NO_ID)
+        vecs, nbrs, ncodes = read_sectors(shard, gids)           # (W,d),(W,R)
+        # exact distances of the expanded nodes -> rerank pool
+        ed = pq.ordered_sum((vecs - state.query[None, :]) ** 2)
+        ed = jnp.where(gids == NO_ID, INF, ed)
+    with jax.named_scope("merge"):
+        if fused:
+            pool_ids, pool_dists = merge_pool_fused(
+                state.pool_ids[None], state.pool_dists[None], gids[None],
+                ed[None], impl=merge_impl,
+            )
+            pool_ids, pool_dists = pool_ids[0], pool_dists[0]
+        else:
+            pool_ids, pool_dists = merge_pool(
+                state.pool_ids, state.pool_dists, gids, ed
+            )
 
-    vecs, nbrs, ncodes = read_sectors(shard, gids)               # (W,d),(W,R)
-    # exact distances of the expanded nodes -> rerank pool
-    ed = pq.ordered_sum((vecs - state.query[None, :]) ** 2)
-    ed = jnp.where(gids == NO_ID, INF, ed)
-    if fused:
-        pool_ids, pool_dists = merge_pool_fused(
-            state.pool_ids[None], state.pool_dists[None], gids[None], ed[None],
-            impl=merge_impl,
-        )
-        pool_ids, pool_dists = pool_ids[0], pool_dists[0]
-    else:
-        pool_ids, pool_dists = merge_pool(
-            state.pool_ids, state.pool_dists, gids, ed
-        )
+        # mark frontier explored.  NOTE: frontier_pos contains duplicate
+        # (clipped) indices for invalid lanes — the scatter must be
+        # order-independent, so accumulate with add and OR the result (a
+        # plain .set() lets a padding lane's no-op write erase a real mark
+        # at the same position).
+        mark = jnp.zeros_like(state.beam_expl, dtype=jnp.int32).at[
+            frontier_pos].add(frontier_mask.astype(jnp.int32))
+        beam_expl = state.beam_expl | (mark > 0)
 
-    # mark frontier explored.  NOTE: frontier_pos contains duplicate (clipped)
-    # indices for invalid lanes — the scatter must be order-independent, so
-    # accumulate with add and OR the result (a plain .set() lets a padding
-    # lane's no-op write erase a real mark at the same position).
-    mark = jnp.zeros_like(state.beam_expl, dtype=jnp.int32).at[frontier_pos].add(
-        frontier_mask.astype(jnp.int32)
-    )
-    beam_expl = state.beam_expl | (mark > 0)
+        # candidate neighbors: dedup against beam and pool
+        cand = nbrs.reshape(-1)                                  # (W*R,)
+        known = _contains(state.beam_ids, cand) | _contains(pool_ids, cand)
+        cand = jnp.where(known, NO_ID, cand)
+    with jax.named_scope("adc"):
+        # PQ distances: sector-resident neighbor codes (AiSAQ mode) or the
+        # replicated global code array (paper baseline)
+        if ncodes is not None:
+            cand_codes = ncodes.reshape(-1, ncodes.shape[-1])    # (W*R, M)
+        else:
+            cand_codes = shard.codes[
+                jnp.clip(cand, 0, shard.codes.shape[0] - 1)]
+        cd_flat = pq.adc(lut[None], cand_codes)[0]
+    with jax.named_scope("merge"):
+        # dedup within candidates (same neighbor from two expanded nodes)
+        order = jnp.lexsort((cand,))
+        cs = cand[order]
+        dupm = jnp.concatenate([jnp.array([False]), cs[1:] == cs[:-1]])
+        cand = jnp.where(dupm, NO_ID, cs)
+        cd = jnp.where(cand == NO_ID, INF, cd_flat[order])
 
-    # candidate neighbors: PQ distances, dedup against beam and pool
-    cand = nbrs.reshape(-1)                                      # (W*R,)
-    known = _contains(state.beam_ids, cand) | _contains(pool_ids, cand)
-    cand = jnp.where(known, NO_ID, cand)
-    # PQ distances: sector-resident neighbor codes (AiSAQ mode) or the
-    # replicated global code array (paper baseline)
-    if ncodes is not None:
-        cand_codes = ncodes.reshape(-1, ncodes.shape[-1])        # (W*R, M)
-    else:
-        cand_codes = shard.codes[jnp.clip(cand, 0, shard.codes.shape[0] - 1)]
-    cd_flat = pq.adc(lut[None], cand_codes)[0]
-    # dedup within candidates (same neighbor from two expanded nodes)
-    order = jnp.lexsort((cand,))
-    cs = cand[order]
-    dupm = jnp.concatenate([jnp.array([False]), cs[1:] == cs[:-1]])
-    cand = jnp.where(dupm, NO_ID, cs)
-    cd = jnp.where(cand == NO_ID, INF, cd_flat[order])
+        if fused:
+            beam_ids, beam_dists, beam_expl = merge_into_beam_fused(
+                state.beam_ids[None], state.beam_dists[None],
+                beam_expl[None], cand[None], cd[None], impl=merge_impl,
+            )
+            beam_ids, beam_dists, beam_expl = (
+                beam_ids[0], beam_dists[0], beam_expl[0]
+            )
+        else:
+            beam_ids, beam_dists, beam_expl = merge_into_beam(
+                state.beam_ids, state.beam_dists, beam_expl, cand, cd
+            )
 
-    if fused:
-        beam_ids, beam_dists, beam_expl = merge_into_beam_fused(
-            state.beam_ids[None], state.beam_dists[None], beam_expl[None],
-            cand[None], cd[None], impl=merge_impl,
+        n_read = jnp.sum(gids != NO_ID)
+        c = state.counters
+        counters = c._replace(
+            hops=c.hops + (n_read > 0).astype(jnp.int32),
+            dist_comps=c.dist_comps + jnp.sum(cand != NO_ID) + n_read,
+            reads=c.reads + n_read,
         )
-        beam_ids, beam_dists, beam_expl = (
-            beam_ids[0], beam_dists[0], beam_expl[0]
-        )
-    else:
-        beam_ids, beam_dists, beam_expl = merge_into_beam(
-            state.beam_ids, state.beam_dists, beam_expl, cand, cd
-        )
-
-    n_read = jnp.sum(gids != NO_ID)
-    c = state.counters
-    counters = c._replace(
-        hops=c.hops + (n_read > 0).astype(jnp.int32),
-        dist_comps=c.dist_comps + jnp.sum(cand != NO_ID) + n_read,
-        reads=c.reads + n_read,
-    )
     return state._replace(
         beam_ids=beam_ids, beam_dists=beam_dists, beam_expl=beam_expl,
         pool_ids=pool_ids, pool_dists=pool_dists, counters=counters,
@@ -421,69 +425,75 @@ def step_disk_batched(
     returned values match vmapping ``step_disk`` exactly (equivalence-tested).
     """
     S, W = masks.shape
-    gids = jnp.where(
-        masks, jnp.take_along_axis(states.beam_ids, fposs, axis=1), NO_ID
-    )                                                            # (S, W)
-    vecs, nbrs, ncodes = read_sectors(shard, gids.reshape(-1))
-    vecs = vecs.reshape(S, W, -1)                                # (S, W, d)
-    R = nbrs.shape[-1]
-    nbrs = nbrs.reshape(S, W, R)
+    with jax.named_scope("sectors"):
+        gids = jnp.where(
+            masks, jnp.take_along_axis(states.beam_ids, fposs, axis=1), NO_ID
+        )                                                        # (S, W)
+        vecs, nbrs, ncodes = read_sectors(shard, gids.reshape(-1))
+        vecs = vecs.reshape(S, W, -1)                            # (S, W, d)
+        R = nbrs.shape[-1]
+        nbrs = nbrs.reshape(S, W, R)
+        ed = pq.ordered_sum((vecs - states.query[:, None, :]) ** 2)  # (S, W)
+        ed = jnp.where(gids == NO_ID, INF, ed)
+    with jax.named_scope("merge"):
+        pool_ids, pool_dists = merge_pool_fused(
+            states.pool_ids, states.pool_dists, gids, ed, impl=merge_impl
+        )
 
-    ed = pq.ordered_sum((vecs - states.query[:, None, :]) ** 2)  # (S, W)
-    ed = jnp.where(gids == NO_ID, INF, ed)
-    pool_ids, pool_dists = merge_pool_fused(
-        states.pool_ids, states.pool_dists, gids, ed, impl=merge_impl
-    )
+        # order-independent explored scatter (see step_disk note)
+        mark = jnp.zeros_like(states.beam_expl, dtype=jnp.int32)
+        mark = mark.at[jnp.arange(S)[:, None], fposs].add(
+            masks.astype(jnp.int32))
+        beam_expl = states.beam_expl | (mark > 0)
 
-    # order-independent explored scatter (see step_disk note)
-    mark = jnp.zeros_like(states.beam_expl, dtype=jnp.int32)
-    mark = mark.at[jnp.arange(S)[:, None], fposs].add(masks.astype(jnp.int32))
-    beam_expl = states.beam_expl | (mark > 0)
-
-    cand = nbrs.reshape(S, W * R)
-    known = _contains_rows(states.beam_ids, cand) | \
-        _contains_rows(pool_ids, cand)
-    cand = jnp.where(known, NO_ID, cand)
-    if ncodes is not None:
-        cand_codes = ncodes.reshape(S, W * R, ncodes.shape[-1])
-    else:
-        cand_codes = shard.codes[jnp.clip(cand, 0, shard.codes.shape[0] - 1)]
+        cand = nbrs.reshape(S, W * R)
+        known = _contains_rows(states.beam_ids, cand) | \
+            _contains_rows(pool_ids, cand)
+        cand = jnp.where(known, NO_ID, cand)
 
     # --- the fused scoring call: all S slots at once -----------------------
-    if adc_impl == "mxu":
-        from repro.kernels.pq_adc.ops import pq_adc_slots
+    with jax.named_scope("adc"):
+        if ncodes is not None:
+            cand_codes = ncodes.reshape(S, W * R, ncodes.shape[-1])
+        else:
+            cand_codes = shard.codes[
+                jnp.clip(cand, 0, shard.codes.shape[0] - 1)]
+        if adc_impl == "mxu":
+            from repro.kernels.pq_adc.ops import pq_adc_slots
 
-        cd_flat = pq_adc_slots(luts, cand_codes.astype(jnp.int32))
-    elif adc_impl == "mxu_tiled":
-        # slot-tiled Pallas grid: (S, C) work, bit-identical to the gather
-        from repro.kernels.pq_adc.ops import pq_adc_slots_tiled
+            cd_flat = pq_adc_slots(luts, cand_codes.astype(jnp.int32))
+        elif adc_impl == "mxu_tiled":
+            # slot-tiled Pallas grid: (S, C) work, bit-identical to the
+            # gather
+            from repro.kernels.pq_adc.ops import pq_adc_slots_tiled
 
-        cd_flat = pq_adc_slots_tiled(luts, cand_codes.astype(jnp.int32))
-    else:
-        cd_flat = pq.adc_slots(luts, cand_codes)                 # (S, W*R)
+            cd_flat = pq_adc_slots_tiled(luts, cand_codes.astype(jnp.int32))
+        else:
+            cd_flat = pq.adc_slots(luts, cand_codes)             # (S, W*R)
 
-    order = jnp.argsort(cand, axis=1, stable=True)
-    cs = jnp.take_along_axis(cand, order, axis=1)
-    dupm = jnp.concatenate(
-        [jnp.zeros((S, 1), bool), cs[:, 1:] == cs[:, :-1]], axis=1
-    )
-    cand = jnp.where(dupm, NO_ID, cs)
-    cd = jnp.where(
-        cand == NO_ID, INF, jnp.take_along_axis(cd_flat, order, axis=1)
-    )
+    with jax.named_scope("merge"):
+        order = jnp.argsort(cand, axis=1, stable=True)
+        cs = jnp.take_along_axis(cand, order, axis=1)
+        dupm = jnp.concatenate(
+            [jnp.zeros((S, 1), bool), cs[:, 1:] == cs[:, :-1]], axis=1
+        )
+        cand = jnp.where(dupm, NO_ID, cs)
+        cd = jnp.where(
+            cand == NO_ID, INF, jnp.take_along_axis(cd_flat, order, axis=1)
+        )
 
-    beam_ids, beam_dists, beam_expl = merge_into_beam_fused(
-        states.beam_ids, states.beam_dists, beam_expl, cand, cd,
-        impl=merge_impl,
-    )
+        beam_ids, beam_dists, beam_expl = merge_into_beam_fused(
+            states.beam_ids, states.beam_dists, beam_expl, cand, cd,
+            impl=merge_impl,
+        )
 
-    n_read = jnp.sum(gids != NO_ID, axis=1)                      # (S,)
-    c = states.counters
-    counters = c._replace(
-        hops=c.hops + (n_read > 0).astype(jnp.int32),
-        dist_comps=c.dist_comps + jnp.sum(cand != NO_ID, axis=1) + n_read,
-        reads=c.reads + n_read,
-    )
+        n_read = jnp.sum(gids != NO_ID, axis=1)                  # (S,)
+        c = states.counters
+        counters = c._replace(
+            hops=c.hops + (n_read > 0).astype(jnp.int32),
+            dist_comps=c.dist_comps + jnp.sum(cand != NO_ID, axis=1) + n_read,
+            reads=c.reads + n_read,
+        )
     return states._replace(
         beam_ids=beam_ids, beam_dists=beam_dists, beam_expl=beam_expl,
         pool_ids=pool_ids, pool_dists=pool_dists, counters=counters,

@@ -172,6 +172,7 @@ def _batann_cell(mesh, multi_pod, sector: bool = False):
         out_trace=sds((n_dev, q_per_dev, cfg.trace_cap, baton.N_TRACE),
                       jnp.int32),
         delivered=sds((n_dev, q_per_dev), bool),
+        local_steps=sds((n_dev,), jnp.int32),
     )
     if sector:
         # AiSAQ sector layout (§Perf iteration): neighbor codes in-sector,
